@@ -89,4 +89,7 @@ def run(num_apps: int = 1200, timeout_s: int = 30):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -91,4 +91,7 @@ def run(num_apps: int = 1200, timeouts=TIMEOUTS):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -212,7 +212,7 @@ def bench_pallas_parity(N: int, T: int):
     comment("pallas interpret-mode parity check (runs the kernel bodies)")
     args = random_problem_arrays(N, T, seed=7)
     d_ref = ops.move_eval(*args, impl="xla")
-    d_pal = ops.move_eval(*args, impl="pallas")
+    d_pal = ops.move_eval(*args, impl="pallas", interpret=True)
     err = float(jnp.max(jnp.abs(d_ref - d_pal))
                 / (jnp.max(jnp.abs(d_ref)) + 1e-9))
     emit("solver_scale/move_eval_pallas_parity",
@@ -220,7 +220,7 @@ def bench_pallas_parity(N: int, T: int):
     t0 = time.perf_counter()
     feas = jnp.ones((N, T), bool)
     s_ref, t_ref = ops.move_eval_best(*args, feas, jnp.int32(5), impl="xla")
-    s_pal, t_pal = ops.move_eval_best(*args, feas, jnp.int32(5), impl="pallas")
+    s_pal, t_pal = ops.move_eval_best(*args, feas, jnp.int32(5), impl="pallas", interpret=True)
     finite = np.isfinite(np.asarray(s_ref))
     scale = float(jnp.max(jnp.abs(jnp.where(finite, s_ref, 0.0)))) + 1e-9
     err = float(np.max(np.abs((np.asarray(s_pal) - np.asarray(s_ref))[finite]))
@@ -265,6 +265,9 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes for CI (seconds, not minutes)")

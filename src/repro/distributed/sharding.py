@@ -18,6 +18,7 @@ from __future__ import annotations
 from typing import Any, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -148,50 +149,56 @@ def batch_shardings(mesh: Mesh, batch_shape) -> Any:
 
 
 def _ambient_mesh() -> Optional[Mesh]:
-    """The mesh in scope, if any (explicit-sharding or legacy context)."""
-    try:                                   # explicit-sharding world
-        m = jax.sharding.get_abstract_mesh()
-        if getattr(m, "axis_names", None):
-            return m
-    except Exception:
-        pass
-    try:                                   # legacy `with mesh:` context
-        m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if getattr(m, "axis_names", None):
-            return m
-    except Exception:
-        pass
-    return None
+    """The concrete mesh set with ``jax.set_mesh``, or None off-mesh.
+
+    Only the installed JAX's own API is consulted: the abstract mesh says
+    whether a mesh is in scope, and ``jax.sharding.get_mesh`` gives its
+    devices.  Call outside ``jit`` (``get_mesh`` refuses a trace).
+    """
+    if jax.sharding.get_abstract_mesh().empty:
+        return None
+    mesh = jax.sharding.get_mesh()
+    return None if mesh.empty else mesh
 
 
-def place_shard_batch(tree: Any) -> Any:
+def place_shard_batch(tree: Any) -> tuple[Any, int]:
     """Place a stacked [S, ...] shard batch over the mesh's batch axes.
 
     The sharded fleet solver stacks S subproblems on a leading axis and
     vmaps over it — embarrassingly parallel, so the leading axis shards
     over ("pod","data") exactly like a model input batch and each device
-    solves its slice of the shards.  Correctness-first like everything
-    here: without an ambient mesh (single-host CPU runs, tests) or when S
-    does not divide the axis, leaves pass through untouched.
+    solves its slice of the shards.  Returns ``(placed, S)``: under a
+    multi-device mesh the leading axis is padded to a multiple of the batch
+    axes' size by repeating the last shard, so the caller keeps the first
+    ``S`` rows of every result.  Off-mesh, or on a one-device mesh, the
+    tree passes through untouched.  A placement the mesh cannot take
+    raises; nothing is silently replicated.
     """
+    leaves = [x for x in jax.tree.leaves(tree) if getattr(x, "ndim", 0) >= 1]
+    if not leaves:
+        raise ValueError("place_shard_batch needs at least one [S, ...] leaf")
+    S = int(leaves[0].shape[0])
     mesh = _ambient_mesh()
-    try:
-        multi = mesh is not None and int(np.prod(mesh.devices.shape)) > 1
-    except Exception:                      # abstract mesh: no devices array
-        multi = False
-    if not multi:
-        return tree
+    if mesh is None or mesh.size == 1:
+        return tree, S
     dp = dp_axes(mesh)
+    missing = [a for a in dp if a not in mesh.axis_names]
+    if missing:
+        raise ValueError(f"mesh {mesh.axis_names} has no batch axis {missing}")
+    width = int(np.prod([mesh.shape[a] for a in dp]))
+    padded = -(-S // width) * width
 
     def one(leaf):
-        if getattr(leaf, "ndim", 0) < 1:
-            return leaf
-        spec = sanitize(P(dp, *([None] * (leaf.ndim - 1))), leaf.shape, mesh)
-        try:
-            return jax.device_put(leaf, NamedSharding(mesh, spec))
-        except Exception:
-            return leaf
-    return jax.tree.map(one, tree)
+        if getattr(leaf, "ndim", 0) < 1 or leaf.shape[0] != S:
+            raise ValueError(
+                f"every shard-batch leaf needs leading axis {S}, got "
+                f"{getattr(leaf, 'shape', None)}")
+        if padded != S:
+            fill = jnp.repeat(leaf[-1:], padded - S, axis=0)
+            leaf = jnp.concatenate([leaf, fill], axis=0)
+        spec = P(dp, *([None] * (leaf.ndim - 1)))
+        return jax.device_put(leaf, NamedSharding(mesh, spec))
+    return jax.tree.map(one, tree), S
 
 
 def cache_shardings(mesh: Mesh, cache_shape, *, kv_shard: str = "heads") -> Any:
@@ -279,26 +286,11 @@ def constrain(x, dims: tuple):
     """
     import jax
 
-    mesh = None
-    try:                                   # explicit-sharding world
-        m = jax.sharding.get_abstract_mesh()
-        if getattr(m, "axis_names", None):
-            mesh = m
-    except Exception:
-        pass
-    if mesh is None:
-        try:                               # legacy `with mesh:` context
-            m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-            if getattr(m, "axis_names", None):
-                mesh = m
-        except Exception:
-            pass
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = set(mesh.axis_names)
-    sizes = dict(zip(mesh.axis_names,
-                     getattr(mesh, "axis_sizes", None)
-                     or mesh.devices.shape))
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     dp = tuple(a for a in ("pod", "data") if a in names)
 
     spec = []

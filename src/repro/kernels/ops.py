@@ -1,12 +1,14 @@
 """jit'd dispatch wrappers: one entry point per kernel, impl-selectable.
 
 ``impl="xla"``    — pure-jnp path (CPU container, dry-run lowering, oracle)
-``impl="pallas"`` — Pallas TPU kernel (``interpret=True`` on CPU for tests;
-                    compiled on real TPU)
+``impl="pallas"`` — Pallas TPU kernel, compiled for the TPU the process
+                    runs on.  Off a TPU it raises unless the caller asks for
+                    the interpreter explicitly with ``interpret=True`` (the
+                    kernel tests do); nothing falls back to interpret mode
+                    on its own.
 
-The dry-run/roofline always lowers the XLA path (Pallas does not lower for
-the CPU backend); on-TPU deployments flip ``ModelConfig.attn_impl`` /
-``LocalSearchConfig`` wiring to "pallas".
+The backend is read per call, never at import: importing this module must
+not start a JAX backend (and with it claim the chip).
 """
 from __future__ import annotations
 
@@ -20,21 +22,27 @@ from repro.kernels.mamba_scan import ssd_chunked_kernel as _mamba_pallas
 from repro.kernels.move_eval import move_eval_best_pallas as _move_best_pallas
 from repro.kernels.move_eval import move_eval_pallas as _move_pallas
 
-_ON_TPU = jax.default_backend() == "tpu"
+
+def _pallas_interpret(interpret: bool) -> bool:
+    """The ``interpret`` flag for a Pallas call, checked against the backend."""
+    if interpret:
+        return True
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f'impl="pallas" needs a TPU (the default backend is {backend!r}); '
+            "pass interpret=True to run the kernel in the Pallas interpreter")
+    return False
 
 
-def _interp() -> bool:
-    return not _ON_TPU
-
-
-def move_eval(*args, impl: str = "xla"):
+def move_eval(*args, impl: str = "xla", interpret: bool = False):
     """delta[N, T] — see core.delta.move_delta_cost for the signature."""
     if impl == "xla":
         return _ref.move_eval_ref(*args)
-    return _move_pallas(*args, interpret=_interp())
+    return _move_pallas(*args, interpret=_pallas_interpret(interpret))
 
 
-def move_eval_best(*args, impl: str = "xla"):
+def move_eval_best(*args, impl: str = "xla", interpret: bool = False):
     """Fused sweep + move-mask + per-app argmin -> (best_score[N], best_tier[N]).
 
     The reduction the batched top-k LocalSearch consumes (it only ever looks
@@ -43,33 +51,36 @@ def move_eval_best(*args, impl: str = "xla"):
     """
     if impl == "xla":
         return _ref.move_eval_best_ref(*args)
-    return _move_best_pallas(*args, interpret=_interp())
+    return _move_best_pallas(*args, interpret=_pallas_interpret(interpret))
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     scale: Optional[float] = None,
-                    impl: str = "xla"):
+                    impl: str = "xla", interpret: bool = False):
     if impl == "xla":
         return _ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                         softcap=softcap, scale=scale)
     return _flash_pallas(q, k, v, causal=causal, window=window,
-                         softcap=softcap, scale=scale, interpret=_interp())
+                         softcap=softcap, scale=scale,
+                         interpret=_pallas_interpret(interpret))
 
 
-def mamba_scan(x, dt, A, Bm, Cm, D, h0=None, *, impl: str = "xla"):
+def mamba_scan(x, dt, A, Bm, Cm, D, h0=None, *, impl: str = "xla",
+               interpret: bool = False):
     if impl == "xla":
         return _ref.mamba_scan_ref(x, dt, A, Bm, Cm, D, h0)
-    return _mamba_pallas(x, dt, A, Bm, Cm, D, h0, interpret=_interp())
+    return _mamba_pallas(x, dt, A, Bm, Cm, D, h0,
+                         interpret=_pallas_interpret(interpret))
 
 
 def flash_decode(q, k, v, kv_len, *, scale=None, softcap=None,
-                 impl: str = "xla"):
+                 impl: str = "xla", interpret: bool = False):
     """Single-token decode attention over an append-only KV cache."""
     if impl == "xla":
         return _ref.flash_decode_ref(q, k, v, kv_len, scale=scale,
                                      softcap=softcap)
     from repro.kernels.flash_decode import flash_decode as _fd
     return _fd(q, k, v, kv_len, scale=scale, softcap=softcap,
-               interpret=_interp())
+               interpret=_pallas_interpret(interpret))

@@ -302,7 +302,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
            "microbatches": microbatches, "tag": tag,
            "overrides": overrides or {}, "kv_shard": kv_shard}
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             fn, args = build_cell(arch, shape_name, mesh,
                                   microbatches=microbatches,
                                   overrides=overrides, kv_shard=kv_shard,

@@ -105,7 +105,7 @@ def main(argv=None):
                               compressor=compressor)
     ckpt = CheckpointManager(args.ckpt_dir, keep=3)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         state = init_train_state(model, jax.random.PRNGKey(args.seed),
                                  compressor=compressor)
         start_step = 0

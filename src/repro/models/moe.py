@@ -126,13 +126,9 @@ def _moe_apply_global(cfg, params, x):
 # ---------------------------------------------------------------------------
 
 def _get_mesh():
-    try:
-        mesh = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if mesh is not None and getattr(mesh, "axis_names", None):
-            return mesh
-    except Exception:
-        pass
-    return None
+    """The mesh set with ``jax.set_mesh`` (abstract inside a trace), or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _moe_apply_ep(cfg, params, x):
@@ -171,7 +167,7 @@ def _moe_apply_ep(cfg, params, x):
             return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
                                      out_specs=out_specs, check_rep=False)
 
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     ep = sizes["model"]
     E, k = cfg.num_experts, cfg.top_k
     B, S, d = x.shape

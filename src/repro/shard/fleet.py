@@ -79,6 +79,22 @@ class FleetDecision:
     coordinator: FleetCoordinator
 
 
+def never_worse(problem, merged: np.ndarray) -> tuple[np.ndarray, bool]:
+    """Keep a merged sharded mapping only if it does not worsen the global
+    objective against the incumbent ``assignment0``.
+
+    Each shard minimizes its own objective, but the balance terms couple
+    every shard through the fleet mean, so the merge of locally better
+    mappings can be globally worse.  Returns ``(mapping, reverted)``.
+    """
+    x0 = np.asarray(problem.assignment0)
+    obj0 = float(global_objective(problem, jnp.asarray(x0)))
+    obj1 = float(global_objective(problem, jnp.asarray(merged)))
+    if obj1 > obj0 + 1e-9:
+        return x0.copy(), True
+    return merged, False
+
+
 def solve_fleet(
     cluster,
     config: FleetConfig | None = None,
@@ -92,11 +108,12 @@ def solve_fleet(
     ``dirty_shards`` (optional bool[S] mask or shard-index iterable) is the
     delta-solve path: only the named shards re-solve, the rest keep their
     incumbent mapping (``shard.solve``).  An all-dirty mask is bit-identical
-    to the full pass.  For a *strict* subset the merged mapping carries a
-    never-worse guard: the global objective is not shard-separable (the
-    balance terms couple through the fleet mean), so a locally-improving
-    delta that worsens the global objective reverts to the incumbent —
-    observable as ``timings["delta_reverted"]``, never silent.
+    to the full pass.  Every merged mapping, full or delta, carries a
+    never-worse guard (``never_worse``): the global objective is not
+    shard-separable (the balance terms couple through the fleet mean), so
+    shard-local improvements that worsen the global objective revert to
+    the incumbent — observable as ``timings["reverted"]`` (and
+    ``timings["delta_reverted"]`` for a strict-subset delta), never silent.
     """
     cfg = config if config is not None else FleetConfig()
     problem = cluster.problem
@@ -129,15 +146,10 @@ def solve_fleet(
     )
     t_solve = time.perf_counter()
 
-    merged = merge_assignment(problem, sharded, res.x)
-    delta_reverted = False
-    if dirty is not None and not dirty.all():
-        x0 = np.asarray(problem.assignment0)
-        obj0 = float(global_objective(problem, jnp.asarray(x0)))
-        obj1 = float(global_objective(problem, jnp.asarray(merged)))
-        if obj1 > obj0 + 1e-9:
-            merged = x0.copy()
-            delta_reverted = True
+    merged, reverted = never_worse(
+        problem, merge_assignment(problem, sharded, res.x)
+    )
+    delta_reverted = reverted and dirty is not None and not dirty.all()
     t_merge = time.perf_counter()
 
     coordinator = FleetCoordinator(
@@ -165,6 +177,7 @@ def solve_fleet(
         "coordinator_s": t_coord - t_merge,
         "total_s": total_s,
         "solved_shards": int(res.solved.sum()) if res.solved.size else plan.num_shards,
+        "reverted": reverted,
         "delta_reverted": delta_reverted,
     }
     return FleetDecision(

@@ -10,7 +10,8 @@ shape-bucketed caching contract as the global solver, observable through
 At ``temperature=0`` the batched top-k LocalSearch never consumes its PRNG
 key, so the batched pass is deterministic and bit-reproducible per shard
 regardless of the split.  Device placement of the stacked batch goes
-through ``distributed.place_shard_batch`` (a no-op off-mesh).
+through ``distributed.place_shard_batch`` under ``jax.set_mesh`` (a no-op
+off-mesh).
 """
 
 from __future__ import annotations
@@ -20,9 +21,10 @@ import time
 
 import jax
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.solver_local import _solve_local_jit
-from repro.distributed.sharding import place_shard_batch
+from repro.distributed.sharding import dp_axes, place_shard_batch
 from repro.shard.partition import ShardedProblem
 
 _TRACE_COUNTS = {"shard_batch": 0}
@@ -91,7 +93,17 @@ def _batched_solver(config: ShardSolveConfig):
 
         def batched(problems, keys, x0):
             _TRACE_COUNTS["shard_batch"] += 1
-            return jax.vmap(one)(problems, keys, x0)
+            mesh = jax.sharding.get_abstract_mesh()
+            if mesh.empty or mesh.size == 1:
+                return jax.vmap(one)(problems, keys, x0)
+            # Under a multi-device mesh every device solves its own slice
+            # of the shards: the vmap runs per device inside shard_map, so
+            # no shard's search ever spans devices and the body has no
+            # collectives (hence no varying-axis bookkeeping to check).
+            spec = P(dp_axes(mesh))
+            return jax.shard_map(
+                jax.vmap(one), in_specs=spec, out_specs=spec, check_vma=False
+            )(problems, keys, x0)
 
         fn = jax.jit(batched)
         _CACHE[key] = fn
@@ -119,7 +131,7 @@ def solve_shards(
     """
     cfg = config if config is not None else ShardSolveConfig()
     S = sharded.num_shards
-    problems = place_shard_batch(sharded.problems)
+    problems = sharded.problems
     keys = jax.random.split(jax.random.PRNGKey(cfg.seed), S)
     x0 = problems.assignment0
     fn = _batched_solver(cfg)
@@ -147,12 +159,15 @@ def solve_shards(
             solved=solved,
         )
 
-    gather = idx
-    sub_problems = jax.tree_util.tree_map(lambda a: a[gather], problems)
-    sub_keys = keys[gather]
-    sub_x0 = x0[gather]
+    sub_problems = jax.tree_util.tree_map(lambda a: a[idx], problems)
+    # Under a multi-device mesh the batch spreads over the batch axes
+    # (padded to a multiple of their size); the padding rows are dropped.
+    batch, n = place_shard_batch((sub_problems, keys[idx], x0[idx]))
     t0 = time.perf_counter()
-    x_sub, it, done, committed, obj = fn(sub_problems, sub_keys, sub_x0)
+    out = fn(*batch)
+    if out[0].shape[0] != n:
+        out = tuple(np.asarray(o)[:n] for o in out)
+    x_sub, it, done, committed, obj = out
     x_sub = jax.block_until_ready(x_sub)
     if idx.size == S:
         return ShardSolveResult(

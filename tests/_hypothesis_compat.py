@@ -7,7 +7,8 @@ stand-in runs each ``@given`` test over a fixed set of seeded examples
 
 Only the API surface this test-suite uses is implemented:
 ``st.integers`` / ``st.sampled_from`` / ``st.composite``,
-``hypothesis.given`` / ``hypothesis.settings`` / ``hypothesis.HealthCheck``.
+``hypothesis.given`` / ``hypothesis.example`` / ``hypothesis.settings`` /
+``hypothesis.HealthCheck``.
 Unknown ``settings`` kwargs (deadline, derandomize, suppress_health_check,
 ...) are accepted and ignored.
 """
@@ -56,12 +57,22 @@ except ImportError:
             return fn
         return deco
 
+    def _example(*vals):
+        """Pin an explicit example; place it below ``@given``."""
+        def deco(fn):
+            fn._compat_examples = [vals, *getattr(fn, "_compat_examples", [])]
+            return fn
+        return deco
+
     def _given(*strategies):
         def deco(fn):
             n = getattr(fn, "_compat_max_examples", 10)
+            pinned = getattr(fn, "_compat_examples", [])
 
             @functools.wraps(fn)
             def wrapper(*args, **kwargs):
+                for vals in pinned:
+                    fn(*args, *vals, **kwargs)
                 for i in range(n):
                     rng = np.random.default_rng(0xC0FFEE + 7919 * i)
                     vals = [s.sample(rng) for s in strategies]
@@ -80,4 +91,5 @@ except ImportError:
         too_slow = "too_slow"
 
     hypothesis = types.SimpleNamespace(
-        given=_given, settings=_settings, HealthCheck=_HealthCheck)
+        given=_given, example=_example, settings=_settings,
+        HealthCheck=_HealthCheck)

@@ -46,6 +46,24 @@ CASES = {
 }
 
 
+# The objective is an f32 reduction, and its last bits depend on the order
+# in which the toolchain sums it: under JAX 0.9 the goldens' assignments,
+# rounds and rejections still match exactly while the objective moved by
+# one f32 ulp.  The pinned assignment SHA already fixes the decision, so
+# the objective is held to a few ulps and everything else stays exact.
+OBJECTIVE_MAX_ULP = 4
+
+
+def _assert_matches_golden(got, want):
+    exact = {k: v for k, v in want.items() if k != "objective"}
+    got_exact = {k: v for k, v in got.items() if k != "objective"}
+    diff = {k: (exact[k], got_exact.get(k)) for k in exact if got_exact.get(k) != exact[k]}
+    assert got_exact == exact, diff
+    np.testing.assert_array_max_ulp(
+        np.float32(got["objective"]), np.float32(want["objective"]), maxulp=OBJECTIVE_MAX_ULP
+    )
+
+
 def _decide(cluster, config):
     return Sptlb(cluster).balance("local", timeout_s=4, config=config)
 
@@ -81,8 +99,7 @@ def test_default_stack_matches_pre_refactor_golden(name):
     if kw.get("move_cost") == "derive":
         kw["move_cost"] = move_costs(cluster.problem)
     got = _record(cluster, _decide(cluster, CoopConfig(max_rounds=8, **kw)))
-    want = GOLDEN[name]
-    assert got == want, {k: (want[k], got[k]) for k in want if got[k] != want[k]}
+    _assert_matches_golden(got, GOLDEN[name])
 
 
 def test_explicit_hierarchy_matches_default():

@@ -51,6 +51,7 @@ from repro.shard import (
     solve_shards,
     stranded_apps,
 )
+from repro.shard.fleet import never_worse
 from repro.shard.solve import ShardSolveConfig
 from repro.sim import Scenario, WorkloadConfig, run_scenario
 from repro.sim.events import CapacityScale, ChurnRate, FlashCrowd, JitterStorm, LinkDegrade
@@ -293,6 +294,10 @@ def _shard_cluster():
 
 @hypothesis.settings(max_examples=N_SHARD_TRAJECTORIES, deadline=None)
 @hypothesis.given(st.integers(0, 10_000))
+# Seed 1379 merges shard-local improvements into a globally worse mapping
+# (5717.29 against an incumbent of 5712.32): the never-worse guard of a
+# full pass must revert it.
+@hypothesis.example(1379)
 def test_fuzz_sharded_passes_hold_invariants(seed):
     rng = np.random.default_rng(seed ^ 0x54A2D)
     cluster = _shard_cluster()
@@ -315,13 +320,20 @@ def test_fuzz_sharded_passes_hold_invariants(seed):
     assert np.array_equal(identity, np.asarray(problem.assignment0)), seed
 
     res = solve_shards(sharded, ShardSolveConfig(max_iters=32))
-    merged = merge_assignment(problem, sharded, res.x)
-    # Hard invariants: nobody stranded, the incumbent never worsened, and
-    # no app left its home shard (cross-shard is coordinator-only).
-    assert stranded_apps(problem, merged) == 0, (seed, num_shards)
+    raw = merge_assignment(problem, sharded, res.x)
+    # Hard invariants of the merge: nobody stranded and no app left its
+    # home shard (cross-shard is coordinator-only).
+    assert stranded_apps(problem, raw) == 0, (seed, num_shards)
+    assert (plan.tier_shard[raw] == plan.app_shard).all(), (seed, num_shards)
+    # The pass (solve_fleet's guard over the merge) never worsens the
+    # incumbent; a revert is exactly the incumbent.
+    merged, reverted = never_worse(problem, raw)
     obj0 = float(objective(problem, problem.assignment0))
     assert float(objective(problem, jnp.asarray(merged))) <= obj0 + 1e-4, seed
-    assert (plan.tier_shard[merged] == plan.app_shard).all(), (seed, num_shards)
+    if reverted:
+        assert np.array_equal(merged, np.asarray(problem.assignment0)), seed
+    else:
+        assert merged is raw, seed
 
 
 # ---------------------------------------------------------------------------

@@ -28,7 +28,7 @@ def test_flash_attention_shapes(B, S, H, KV, D):
     k = jnp.asarray(RNG.normal(0, 1, (B, S, KV, D)), jnp.float32)
     v = jnp.asarray(RNG.normal(0, 1, (B, S, KV, D)), jnp.float32)
     o_ref = ops.flash_attention(q, k, v, impl="xla")
-    o_pal = ops.flash_attention(q, k, v, impl="pallas")
+    o_pal = ops.flash_attention(q, k, v, impl="pallas", interpret=True)
     np.testing.assert_allclose(o_pal, o_ref, atol=3e-5, rtol=3e-5)
 
 
@@ -46,7 +46,7 @@ def test_flash_attention_features(window, softcap, causal):
     v = jnp.asarray(RNG.normal(0, 1, (B, S, KV, D)), jnp.float32)
     kw = dict(causal=causal, window=window, softcap=softcap)
     o_ref = ops.flash_attention(q, k, v, impl="xla", **kw)
-    o_pal = ops.flash_attention(q, k, v, impl="pallas", **kw)
+    o_pal = ops.flash_attention(q, k, v, impl="pallas", interpret=True, **kw)
     np.testing.assert_allclose(o_pal, o_ref, atol=3e-5, rtol=3e-5)
 
 
@@ -56,7 +56,7 @@ def test_flash_attention_bf16():
     k = jnp.asarray(RNG.normal(0, 1, (B, S, KV, D)), jnp.bfloat16)
     v = jnp.asarray(RNG.normal(0, 1, (B, S, KV, D)), jnp.bfloat16)
     o_ref = ops.flash_attention(q, k, v, impl="xla")
-    o_pal = ops.flash_attention(q, k, v, impl="pallas")
+    o_pal = ops.flash_attention(q, k, v, impl="pallas", interpret=True)
     np.testing.assert_allclose(np.asarray(o_pal, np.float32),
                                np.asarray(o_ref, np.float32),
                                atol=3e-2, rtol=3e-2)
@@ -79,7 +79,7 @@ def test_mamba_scan_shapes(B, S, H, P, N):
     Cm = jnp.asarray(RNG.normal(0, 1, (B, S, N)), jnp.float32)
     D = jnp.asarray(RNG.uniform(0.5, 1.5, H), jnp.float32)
     y_ref, h_ref = ops.mamba_scan(x, dt, A, Bm, Cm, D, impl="xla")
-    y_pal, h_pal = ops.mamba_scan(x, dt, A, Bm, Cm, D, impl="pallas")
+    y_pal, h_pal = ops.mamba_scan(x, dt, A, Bm, Cm, D, impl="pallas", interpret=True)
     np.testing.assert_allclose(y_pal, y_ref, atol=5e-5, rtol=5e-5)
     np.testing.assert_allclose(h_pal, h_ref, atol=5e-5, rtol=5e-5)
 
@@ -95,7 +95,7 @@ def test_mamba_scan_carry_state():
     D = jnp.asarray(RNG.uniform(0.5, 1.5, H), jnp.float32)
     h0 = jnp.asarray(RNG.normal(0, 0.3, (B, H, P, N)), jnp.float32)
     y_ref, h_ref = ops.mamba_scan(x, dt, A, Bm, Cm, D, h0, impl="xla")
-    y_pal, h_pal = ops.mamba_scan(x, dt, A, Bm, Cm, D, h0, impl="pallas")
+    y_pal, h_pal = ops.mamba_scan(x, dt, A, Bm, Cm, D, h0, impl="pallas", interpret=True)
     np.testing.assert_allclose(y_pal, y_ref, atol=5e-5, rtol=5e-5)
     np.testing.assert_allclose(h_pal, h_ref, atol=5e-5, rtol=5e-5)
 
@@ -130,7 +130,7 @@ def test_mamba_chunked_matches_recurrent():
 def test_move_eval_matches_ref(N, T):
     args = random_problem_arrays(N, T, seed=N + T)
     d_ref = ops.move_eval(*args, impl="xla")
-    d_pal = ops.move_eval(*args, impl="pallas")
+    d_pal = ops.move_eval(*args, impl="pallas", interpret=True)
     scale = float(jnp.max(jnp.abs(d_ref))) + 1e-9
     np.testing.assert_allclose(d_pal / scale, d_ref / scale, atol=1e-5)
 
@@ -169,7 +169,7 @@ def test_move_eval_best_matches_ref(N, T, moves_left):
     feas = jnp.asarray(rng.random((N, T)) > 0.2)
     ml = jnp.int32(moves_left)
     s_ref, t_ref = ops.move_eval_best(*args, feas, ml, impl="xla")
-    s_pal, t_pal = ops.move_eval_best(*args, feas, ml, impl="pallas")
+    s_pal, t_pal = ops.move_eval_best(*args, feas, ml, impl="pallas", interpret=True)
     finite = np.isfinite(np.asarray(s_ref))
     # same apps marked infeasible (+inf)
     assert np.array_equal(np.isfinite(np.asarray(s_pal)), finite)
@@ -224,7 +224,7 @@ def test_flash_decode_matches_ref(B, Smax, H, KV, D, kv_len, softcap):
     k = jnp.asarray(rng.normal(0, 1, (B, Smax, KV, D)), jnp.float32)
     v = jnp.asarray(rng.normal(0, 1, (B, Smax, KV, D)), jnp.float32)
     o_ref = ops.flash_decode(q, k, v, kv_len, softcap=softcap, impl="xla")
-    o_pal = ops.flash_decode(q, k, v, kv_len, softcap=softcap, impl="pallas")
+    o_pal = ops.flash_decode(q, k, v, kv_len, softcap=softcap, impl="pallas", interpret=True)
     np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
                                atol=3e-5, rtol=3e-5)
 
@@ -236,7 +236,7 @@ def test_flash_decode_bf16():
     k = jnp.asarray(rng.normal(0, 1, (B, Smax, KV, D)), jnp.bfloat16)
     v = jnp.asarray(rng.normal(0, 1, (B, Smax, KV, D)), jnp.bfloat16)
     o_ref = ops.flash_decode(q, k, v, 200, impl="xla")
-    o_pal = ops.flash_decode(q, k, v, 200, impl="pallas")
+    o_pal = ops.flash_decode(q, k, v, 200, impl="pallas", interpret=True)
     np.testing.assert_allclose(np.asarray(o_pal, np.float32),
                                np.asarray(o_ref, np.float32),
                                atol=3e-2, rtol=3e-2)

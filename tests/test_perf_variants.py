@@ -100,7 +100,8 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     from repro.models import build_model, reduce_for_smoke
     from repro.models import moe as MOE
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     key = jax.random.PRNGKey(0)
 
     # --- EP MoE == global MoE ---
@@ -108,7 +109,7 @@ _SUBPROCESS_PROG = textwrap.dedent("""
                               param_dtype="float32")
     params = MOE.moe_init(cfg, key, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model))
-    with mesh:
+    with jax.set_mesh(mesh):
         y_g, _ = jax.jit(lambda p, x: MOE._moe_apply_global(cfg, p, x))(params, x)
         cfg_ep = dataclasses.replace(cfg, moe_impl="ep")
         y_e, _ = jax.jit(lambda p, x: MOE.moe_apply(cfg_ep, p, x))(params, x)
@@ -120,7 +121,7 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     model = build_model(cfg)
     p = model.init(key)
     batch = {"tokens": jax.random.randint(key, (8, 16), 0, cfg.vocab_size)}
-    with mesh:
+    with jax.set_mesh(mesh):
         base, _ = jax.jit(model.forward_train)(p, batch)
         cfg_b = dataclasses.replace(cfg, attn_batch_shard=True,
                                     activation_sharding=True)

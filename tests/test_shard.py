@@ -171,6 +171,7 @@ def test_solve_fleet_end_to_end(cluster):
         "coordinator_s",
         "total_s",
         "solved_shards",
+        "reverted",
         "delta_reverted",
     }
     assert fd.timings["solved_shards"] == 3
