@@ -56,7 +56,6 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-import time
 import warnings
 from typing import Optional
 
@@ -73,6 +72,7 @@ from repro.core.problem import utilization_fraction
 from repro.core.shedding import LoadShedder, ShedConfig
 from repro.core.sptlb import Sptlb
 from repro.core.telemetry import ClusterState
+from repro.spans import span
 
 
 class Mode(str, enum.Enum):
@@ -617,7 +617,8 @@ class BalanceController:
         scores."""
         inp = inp if inp is not None else TickInput()
         self._observe_phase(inp)
-        plan = self._decide_phase(inp)
+        with span("controller.decide"):
+            plan = self._decide_phase(inp)
         return self._actuate_phase(inp, plan)
 
     def _observe_phase(self, inp: TickInput) -> None:
@@ -759,58 +760,57 @@ class BalanceController:
             ev.budget_limited = True
             self.budget_overruns += 1
         elif triggered:
-            t0 = time.perf_counter()
-            coop_cfg = dataclasses.replace(
-                self.config.coop, plan=outlook, move_cost=move_costs(p),
-                cost_budget=remaining, shed=shed_plan)
-            balance_cluster = self.cluster
-            if fault is not None:
-                coop_cfg = dataclasses.replace(coop_cfg, breakers=self.board)
-                if self.mode is not Mode.NORMAL:
-                    # Mode-restricted movement: everyone outside the
-                    # evacuation set is held home by a standing avoid mask
-                    # (the solver literally cannot propose other moves).
-                    balance_cluster = dataclasses.replace(
-                        self.cluster, problem=p.with_avoid(
-                            jnp.asarray(self._mode_avoid(p, evac))))
-            dirty = inp.dirty_shards
-            delta = dirty is not None
-            shards = self.config.shards or (inp.num_shards if delta else None)
-            if shards:
-                # Sharded fleet path: partitioned batched solve + the
-                # FleetCoordinator's priced boundary migrations, under the
-                # same BalanceDecision contract (plan steering, shed caps,
-                # and the movement budget all ride coop_cfg).  A dirty-region
-                # scope from the service loop turns this into a delta solve;
-                # without a standing config.shards, *only* delta solves route
-                # here and full passes keep the global engine.
-                from repro.shard import FleetConfig, balance_fleet
-                decision = balance_fleet(
-                    balance_cluster,
-                    fleet=FleetConfig(num_shards=shards,
-                                      timeout_s=self.config.timeout_s),
-                    coop=coop_cfg,
-                    dirty_shards=dirty)
-            else:
-                self._sptlb.cluster = balance_cluster
-                decision = self._sptlb.balance(
-                    self.config.engine, timeout_s=self.config.timeout_s,
-                    config=coop_cfg, hierarchy=self.hierarchy_override)
-                self._sptlb.cluster = self.cluster
-            if fault is not None:
-                coop = decision.cooperation
-                # Solver distress means the solver *couldn't answer*, not
-                # that the answer was hard: an unaccepted pass that still
-                # had rounds left exited on wall-clock (a brownout), and an
-                # unconverged zero-iteration result is the bus's dead-solver
-                # fallback.  A pass that merely exhausted its round budget
-                # on a contentious workload is healthy.
-                timed_out = (coop is not None and not coop.accepted
-                             and coop.timings.rounds <= coop_cfg.max_rounds)
-                dead = (decision.solve.iterations == 0
-                        and not decision.solve.converged)
-                self._note_solve(not (timed_out or dead))
-            ev.time_s = time.perf_counter() - t0
+            with span("controller.balance", into=ev, key="time_s"):
+                coop_cfg = dataclasses.replace(
+                    self.config.coop, plan=outlook, move_cost=move_costs(p),
+                    cost_budget=remaining, shed=shed_plan)
+                balance_cluster = self.cluster
+                if fault is not None:
+                    coop_cfg = dataclasses.replace(coop_cfg, breakers=self.board)
+                    if self.mode is not Mode.NORMAL:
+                        # Mode-restricted movement: everyone outside the
+                        # evacuation set is held home by a standing avoid mask
+                        # (the solver literally cannot propose other moves).
+                        balance_cluster = dataclasses.replace(
+                            self.cluster, problem=p.with_avoid(
+                                jnp.asarray(self._mode_avoid(p, evac))))
+                dirty = inp.dirty_shards
+                delta = dirty is not None
+                shards = self.config.shards or (inp.num_shards if delta else None)
+                if shards:
+                    # Sharded fleet path: partitioned batched solve + the
+                    # FleetCoordinator's priced boundary migrations, under the
+                    # same BalanceDecision contract (plan steering, shed caps,
+                    # and the movement budget all ride coop_cfg).  A dirty-region
+                    # scope from the service loop turns this into a delta solve;
+                    # without a standing config.shards, *only* delta solves route
+                    # here and full passes keep the global engine.
+                    from repro.shard import FleetConfig, balance_fleet
+                    decision = balance_fleet(
+                        balance_cluster,
+                        fleet=FleetConfig(num_shards=shards,
+                                          timeout_s=self.config.timeout_s),
+                        coop=coop_cfg,
+                        dirty_shards=dirty)
+                else:
+                    self._sptlb.cluster = balance_cluster
+                    decision = self._sptlb.balance(
+                        self.config.engine, timeout_s=self.config.timeout_s,
+                        config=coop_cfg, hierarchy=self.hierarchy_override)
+                    self._sptlb.cluster = self.cluster
+                if fault is not None:
+                    coop = decision.cooperation
+                    # Solver distress means the solver *couldn't answer*, not
+                    # that the answer was hard: an unaccepted pass that still
+                    # had rounds left exited on wall-clock (a brownout), and an
+                    # unconverged zero-iteration result is the bus's dead-solver
+                    # fallback.  A pass that merely exhausted its round budget
+                    # on a contentious workload is healthy.
+                    timed_out = (coop is not None and not coop.accepted
+                                 and coop.timings.rounds <= coop_cfg.max_rounds)
+                    dead = (decision.solve.iterations == 0
+                            and not decision.solve.converged)
+                    self._note_solve(not (timed_out or dead))
             ev.d2b_after = decision.difference_to_balance
             ev.moved = decision.projected.num_moved
             ev.movement_cost = decision.movement_cost

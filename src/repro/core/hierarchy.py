@@ -83,6 +83,7 @@ from repro.core.problem import Problem, bucket_size
 from repro.core.solver_local import SolveResult
 from repro.core.telemetry import ClusterState
 from repro.kernels.pack import DispatchStats, pack_ffd, pack_ffd_tiers
+from repro.spans import span
 
 # The latency budget/relax constants are re-exported from ``core.levels``
 # (the single source of truth) — historical importers read them from here.
@@ -456,7 +457,7 @@ def _pad_ids(ids: np.ndarray, sentinel: int, minimum: int = 32) -> np.ndarray:
     return out
 
 
-def _finish_timings(timings: CoopTimings, total_s: float) -> CoopTimings:
+def _finish_timings(timings: CoopTimings) -> CoopTimings:
     # Device phases are the solver and the levels' compiled dispatches
     # (``device_time_s``, already split out of each level's glue by
     # ``_collect_level_counters``); everything else counts as host-side —
@@ -464,7 +465,7 @@ def _finish_timings(timings: CoopTimings, total_s: float) -> CoopTimings:
     # undercount host work.  ``bus_overhead_frac`` narrows further: the
     # wall-clock that belongs to no phase at all (the generic bus's own
     # routing), the number the PR-5 regression gate pins.
-    timings.total_s = total_s
+    total_s = timings.total_s
     device_s = timings.solve_s + sum(
         float(sub.get("device_s", 0.0)) for sub in timings.levels.values())
     timings.host_side_frac = (
@@ -524,13 +525,14 @@ class _BreakerPass:
     def vet(self, level, proposal: Proposal,
             timings: CoopTimings) -> np.ndarray:
         brk = self.board.breaker(level.name)
-        t = time.perf_counter()
-        try:
-            rej = np.asarray(level.vet(proposal), np.int64)
-        except Exception:
-            brk.note_failure()
-            rej = np.asarray(proposal.candidates, np.int64)  # fail closed
-        elapsed = time.perf_counter() - t
+        clock = {"elapsed": 0.0}
+        with span("bus.vet", into=clock, key="elapsed", level=level.name):
+            try:
+                rej = np.asarray(level.vet(proposal), np.int64)
+            except Exception:
+                brk.note_failure()
+                rej = np.asarray(proposal.candidates, np.int64)  # fail closed
+        elapsed = clock["elapsed"]
         timings.add_level_time(level.name, elapsed)
         limit = self.board.config.level_timeout_s
         if limit is not None and elapsed > limit:
@@ -593,10 +595,9 @@ def _vet_timed(level, proposal: Proposal, timings: CoopTimings,
                breakers: Optional[_BreakerPass] = None) -> np.ndarray:
     if breakers is not None and breakers.board is not None:
         return breakers.vet(level, proposal, timings)
-    t = time.perf_counter()
-    rej = np.asarray(level.vet(proposal), np.int64)
-    timings.add_level_time(level.name, time.perf_counter() - t)
-    return rej
+    with span("bus.vet", into=timings.level(level.name), key="level_s",
+              level=level.name):
+        return np.asarray(level.vet(proposal), np.int64)
 
 
 def _revert_fixpoint(levels, x_np: np.ndarray, x0_np: np.ndarray,
@@ -615,35 +616,36 @@ def _revert_fixpoint(levels, x_np: np.ndarray, x0_np: np.ndarray,
     pre-loads the returner set (budget trimming reverts moves before the
     fixpoint starts).
     """
-    x_np = x_np.copy()
-    empty = np.empty(0, np.int64)
-    pending = {lv.name: (seed_returners if seed_returners is not None
-                         else empty) for lv in levels}
-    while True:
-        rejected_any = False
-        for lv in levels:
-            movers = np.where(x_np != x0_np)[0]
-            returners = pending[lv.name]
-            if movers.size == 0 and returners.size == 0:
-                continue
-            rej = _vet_timed(lv, Proposal(x_np, x0_np, movers,
-                                          returners=returners, final=True),
-                             timings, breakers)
-            pending[lv.name] = empty
-            # Defensive protocol clamp: only movers can be rejected (the
-            # incumbent placement is every revert's fallback).  A plugin
-            # level that bounced a returner would otherwise no-op the
-            # revert while keeping rejected_any set — an infinite fixpoint.
-            rej = rej[x_np[rej] != x0_np[rej]]
-            if rej.size:
-                x_np[rej] = x0_np[rej]
-                for other in levels:
-                    prev = pending[other.name]
-                    pending[other.name] = (rej if prev.size == 0
-                                           else np.concatenate([prev, rej]))
-                rejected_any = True
-        if not rejected_any:
-            return x_np
+    with span("bus.revert"):
+        x_np = x_np.copy()
+        empty = np.empty(0, np.int64)
+        pending = {lv.name: (seed_returners if seed_returners is not None
+                             else empty) for lv in levels}
+        while True:
+            rejected_any = False
+            for lv in levels:
+                movers = np.where(x_np != x0_np)[0]
+                returners = pending[lv.name]
+                if movers.size == 0 and returners.size == 0:
+                    continue
+                rej = _vet_timed(lv, Proposal(x_np, x0_np, movers,
+                                              returners=returners, final=True),
+                                 timings, breakers)
+                pending[lv.name] = empty
+                # Defensive protocol clamp: only movers can be rejected (the
+                # incumbent placement is every revert's fallback).  A plugin
+                # level that bounced a returner would otherwise no-op the
+                # revert while keeping rejected_any set — an infinite fixpoint.
+                rej = rej[x_np[rej] != x0_np[rej]]
+                if rej.size:
+                    x_np[rej] = x0_np[rej]
+                    for other in levels:
+                        prev = pending[other.name]
+                        pending[other.name] = (rej if prev.size == 0
+                                               else np.concatenate([prev, rej]))
+                    rejected_any = True
+            if not rejected_any:
+                return x_np
 
 
 def enforce_cost_budget(cluster: ClusterState, res: SolveResult,
@@ -668,40 +670,41 @@ def enforce_cost_budget(cluster: ClusterState, res: SolveResult,
     moves, so the budget holds after the fixpoint too.  ``levels`` may be
     empty (hierarchy-unaware engines: no re-vet to run).
     """
-    x_np = np.asarray(res.assignment)
-    total = movement_cost_of(x_np, x0_np, move_cost)
-    timings["movement_cost"] = total
-    if total <= cost_budget + 1e-9:
-        return res
-    x_np = x_np.copy()
-    moved = np.where(x_np != x0_np)[0]
-    per = (np.ones(moved.size, np.float32) if move_cost is None
-           else np.asarray(move_cost)[moved])
-    p = cluster.problem
-    slo_ok_home = np.asarray(p.slo_allowed)[
-        x0_np[moved], np.asarray(p.slo)[moved]]
-    # lexsort: last key is primary — strand-fixers (slo_ok_home False) first,
-    # then ascending per-move cost within each class.
-    order = np.lexsort((per, slo_ok_home))
-    keep = np.zeros(moved.size, bool)
-    spent = 0.0
-    for i in order:
-        if spent + per[i] <= cost_budget + 1e-9:
-            spent += per[i]
-            keep[i] = True
-    reverted = moved[~keep]
-    x_np[reverted] = x0_np[reverted]
-    timings["budget_trimmed"] = (timings.get("budget_trimmed", 0)
-                                 + int(reverted.size))
-    if levels and reverted.size:
-        x_np = _revert_fixpoint(levels, x_np, x0_np, timings,
-                                seed_returners=reverted, breakers=breakers)
-    x_final = jnp.asarray(x_np)
-    timings["movement_cost"] = movement_cost_of(x_np, x0_np, move_cost)
-    return dataclasses.replace(
-        res, assignment=x_final,
-        num_moved=int(np.sum(x_np != x0_np)),
-        objective=float(_objective(cluster.problem, x_final)))
+    with span("bus.budget"):
+        x_np = np.asarray(res.assignment)
+        total = movement_cost_of(x_np, x0_np, move_cost)
+        timings["movement_cost"] = total
+        if total <= cost_budget + 1e-9:
+            return res
+        x_np = x_np.copy()
+        moved = np.where(x_np != x0_np)[0]
+        per = (np.ones(moved.size, np.float32) if move_cost is None
+               else np.asarray(move_cost)[moved])
+        p = cluster.problem
+        slo_ok_home = np.asarray(p.slo_allowed)[
+            x0_np[moved], np.asarray(p.slo)[moved]]
+        # lexsort: last key is primary — strand-fixers (slo_ok_home False) first,
+        # then ascending per-move cost within each class.
+        order = np.lexsort((per, slo_ok_home))
+        keep = np.zeros(moved.size, bool)
+        spent = 0.0
+        for i in order:
+            if spent + per[i] <= cost_budget + 1e-9:
+                spent += per[i]
+                keep[i] = True
+        reverted = moved[~keep]
+        x_np[reverted] = x0_np[reverted]
+        timings["budget_trimmed"] = (timings.get("budget_trimmed", 0)
+                                     + int(reverted.size))
+        if levels and reverted.size:
+            x_np = _revert_fixpoint(levels, x_np, x0_np, timings,
+                                    seed_returners=reverted, breakers=breakers)
+        x_final = jnp.asarray(x_np)
+        timings["movement_cost"] = movement_cost_of(x_np, x0_np, move_cost)
+        return dataclasses.replace(
+            res, assignment=x_final,
+            num_moved=int(np.sum(x_np != x0_np)),
+            objective=float(_objective(cluster.problem, x_final)))
 
 
 def _restart_phase(cluster: ClusterState, problem: Problem, res: SolveResult,
@@ -786,6 +789,23 @@ def cooperate(
     ``timings.breakers``; ``None`` keeps the exact pre-breaker code path.
     """
     cfg = config if config is not None else CoopConfig()
+    timings = CoopTimings()
+    with span("bus.pass", into=timings, key="total_s"):
+        out, levels, bp = _cooperate_pass(cluster, solve_fn, cfg, hierarchy,
+                                          timings)
+    if bp is not None:
+        bp.finish(timings)
+        _collect_level_counters(timings, levels)
+    out.total_time_s = timings.total_s
+    out.result.extra["coop_timings"] = _finish_timings(timings)
+    return out
+
+
+def _cooperate_pass(cluster: ClusterState, solve_fn, cfg: CoopConfig,
+                    hierarchy: Optional[Hierarchy], timings: CoopTimings):
+    """The body of ``cooperate``: fills ``timings`` and returns the result
+    (its ``total_time_s`` still unset), the bound levels and the breaker
+    mediator (None for the variants that never consult the stack)."""
     wallclock = cfg.timeout_s if cfg.timeout_s is not None else float("inf")
 
     t0 = time.perf_counter()
@@ -797,32 +817,26 @@ def cooperate(
         # (the host scheduler's demand transfer, the region matrices) just
         # to return early.  The legacy flat keys (region_s, host_rejections,
         # pack counters) stay resolvable at their historical zeros.
-        timings = CoopTimings.for_levels(DEFAULT_LEVELS)
+        timings.add_levels(DEFAULT_LEVELS)
 
         def timed_solve0(p, **kw):
-            t = time.perf_counter()
-            r = solve_fn(p, **kw)
-            timings.solve_s += time.perf_counter() - t
-            return r
+            with span("bus.solve", into=timings, key="solve_s"):
+                return solve_fn(p, **kw)
 
         if use_variant == "w_cnst":
             problem = problem.with_avoid(jnp.asarray(region_overlap_avoid(cluster)))
         res = timed_solve0(problem)
         res = enforce_cost_budget(cluster, res, np.asarray(problem.assignment0),
                                   cfg.move_cost, cfg.cost_budget, (), timings)
-        total = time.perf_counter() - t0
-        res.extra["coop_timings"] = _finish_timings(timings, total)
-        return CooperationResult(res, use_variant, 1, 0, total, True,
-                                 timings=timings)
+        return (CooperationResult(res, use_variant, 1, 0, 0.0, True,
+                                  timings=timings), (), None)
 
     assert use_variant == "manual_cnst", use_variant
     levels = cfg.hierarchy(hierarchy).bind(cluster)
     bp = _BreakerPass(cfg.breakers, levels)
     active = bp.active(levels)
-    timings = CoopTimings.for_levels(
-        [lv.name for lv in levels],
-        premask=any(cfg.premask_for(lv.name) for lv in levels),
-        round_costs=[])
+    timings.add_levels(lv.name for lv in levels)
+    timings.premask = any(cfg.premask_for(lv.name) for lv in levels)
     if cfg.plan is not None:
         for lv in active:
             bp.relax(lv, cfg.plan, cluster)
@@ -831,12 +845,12 @@ def cooperate(
     x0_dev = problem.assignment0
 
     def timed_solve(p, **kw):
-        t = time.perf_counter()
-        try:
-            r = solve_fn(p, **kw)
-        except Exception:
-            if bp.board is None:
-                raise
+        with span("bus.solve", into=timings, key="solve_s"):
+            try:
+                return solve_fn(p, **kw)
+            except Exception:
+                if bp.board is None:
+                    raise
             # Solver fault under an armed board: fall back to the best
             # mapping already in hand — the warm start when one was passed,
             # else the identity mapping (stay-home was vetted by every
@@ -844,13 +858,11 @@ def cooperate(
             # fixpoint downstream treats it like any other proposal.
             init = kw.get("init_assignment")
             x_fb = jnp.asarray(init) if init is not None else x0_dev
-            r = SolveResult(
+            return SolveResult(
                 assignment=x_fb, iterations=0, converged=False,
                 objective=float(_objective(cluster.problem, x_fb)),
                 num_moved=int(np.sum(np.asarray(x_fb) != x0_np)),
                 solve_time_s=0.0)
-        timings.solve_s += time.perf_counter() - t
-        return r
 
     home_open = np.arange(problem.num_apps)
     if any(cfg.premask_for(lv.name) for lv in levels) or bp.bypassed:
@@ -866,13 +878,13 @@ def cooperate(
         for lv in levels:
             if not cfg.premask_for(lv.name) and lv.name not in bp.bypassed:
                 continue
-            t = time.perf_counter()
-            pre = bp.premask(lv, problem)
-            if pre is not None:
-                pre = np.asarray(pre, bool).copy()
-                pre[home_open, x0_np] = False
-                problem = problem.with_avoid(jnp.asarray(pre))
-            timings.add_level_time(lv.name, time.perf_counter() - t)
+            with span("bus.premask", into=timings.level(lv.name),
+                      key="level_s", level=lv.name):
+                pre = bp.premask(lv, problem)
+                if pre is not None:
+                    pre = np.asarray(pre, bool).copy()
+                    pre[home_open, x0_np] = False
+                    problem = problem.with_avoid(jnp.asarray(pre))
 
     # The avoid/ack mask lives on device for the whole pass and is updated
     # by scatter ops; ``base_avoid`` (caller avoids + the premasks + any
@@ -923,14 +935,10 @@ def cooperate(
                 res = enforce_cost_budget(cluster, res, x0_np, cfg.move_cost,
                                           cfg.cost_budget, active, timings,
                                           breakers=bp)
-                total = time.perf_counter() - t0
                 timings.rounds = rounds
-                bp.finish(timings)
-                _collect_level_counters(timings, levels)
-                res.extra["coop_timings"] = _finish_timings(timings, total)
-                return CooperationResult(res, use_variant, rounds,
-                                         total_rejections, total, True,
-                                         timings=timings)
+                return (CooperationResult(res, use_variant, rounds,
+                                          total_rejections, 0.0, True,
+                                          timings=timings), levels, bp)
             # The proposal was accepted whole, but the solver ran out of
             # sweep budget with improving moves left.  Spend the remaining
             # rounds continuing the search (warm-started, same mask) — the
@@ -954,35 +962,34 @@ def cooperate(
         # every round, so the loop converges instead of exploring forever.
         # All of it is one compiled scatter step on the standing mask — no
         # [N, T] numpy rebuild, no re-upload, no per-shape recompiles.
-        t = time.perf_counter()
-        total_rejections += int(rej_n.size)
-        acked = candidates                       # ack'd placements
-        N = x_np.shape[0]
-        rej_pad = _pad_ids(rej_n, N)
-        acked_pad = _pad_ids(acked, N)
-        avoid, x_accepted = _feedback_update(
-            avoid, base_avoid, res.assignment, x0_dev,
-            jnp.asarray(rej_pad),
-            jnp.asarray(np.take(x_np, rej_pad, mode="clip")),
-            jnp.asarray(acked_pad),
-            jnp.asarray(np.take(x_np, acked_pad, mode="clip")),
-            jnp.asarray(np.take(x0_np, acked_pad, mode="clip")))
-        # Level escalation hook: a level may answer a rejection round with
-        # extra *standing* avoid rows (beyond the per-(app, dest) scatter).
-        state = BusState(round=rounds, x=x_np, x0=x0_np, rejections=round_rej)
-        extra_masks = []
-        for lv in active:
-            extra = bp.feedback(lv, state)
-            if extra is not None:
-                extra = np.asarray(extra, bool).copy()
-                extra[home_open, x0_np] = False  # staying home stays legal
-                extra_masks.append(extra)
-        if extra_masks:
-            mask_dev = jnp.asarray(np.logical_or.reduce(extra_masks))
-            base_avoid = base_avoid | mask_dev
-            avoid = avoid | mask_dev
-        problem = dataclasses.replace(problem, avoid=avoid)
-        timings.feedback_s += time.perf_counter() - t
+        with span("bus.feedback", into=timings, key="feedback_s"):
+            total_rejections += int(rej_n.size)
+            acked = candidates                       # ack'd placements
+            N = x_np.shape[0]
+            rej_pad = _pad_ids(rej_n, N)
+            acked_pad = _pad_ids(acked, N)
+            avoid, x_accepted = _feedback_update(
+                avoid, base_avoid, res.assignment, x0_dev,
+                jnp.asarray(rej_pad),
+                jnp.asarray(np.take(x_np, rej_pad, mode="clip")),
+                jnp.asarray(acked_pad),
+                jnp.asarray(np.take(x_np, acked_pad, mode="clip")),
+                jnp.asarray(np.take(x0_np, acked_pad, mode="clip")))
+            # Level escalation hook: a level may answer a rejection round with
+            # extra *standing* avoid rows (beyond the per-(app, dest) scatter).
+            state = BusState(round=rounds, x=x_np, x0=x0_np, rejections=round_rej)
+            extra_masks = []
+            for lv in active:
+                extra = bp.feedback(lv, state)
+                if extra is not None:
+                    extra = np.asarray(extra, bool).copy()
+                    extra[home_open, x0_np] = False  # staying home stays legal
+                    extra_masks.append(extra)
+            if extra_masks:
+                mask_dev = jnp.asarray(np.logical_or.reduce(extra_masks))
+                base_avoid = base_avoid | mask_dev
+                avoid = avoid | mask_dev
+            problem = dataclasses.replace(problem, avoid=avoid)
 
         res = timed_solve(problem, init_assignment=x_accepted)
         rounds += 1
@@ -1004,10 +1011,6 @@ def cooperate(
         objective=float(_objective(cluster.problem, x_final)))
     res = enforce_cost_budget(cluster, res, x0_np, cfg.move_cost,
                               cfg.cost_budget, active, timings, breakers=bp)
-    total = time.perf_counter() - t0
     timings.rounds = rounds
-    bp.finish(timings)
-    _collect_level_counters(timings, levels)
-    res.extra["coop_timings"] = _finish_timings(timings, total)
-    return CooperationResult(res, use_variant, rounds, total_rejections,
-                             total, False, timings=timings)
+    return (CooperationResult(res, use_variant, rounds, total_rejections,
+                              0.0, False, timings=timings), levels, bp)

@@ -332,17 +332,22 @@ class CoopTimings:
     @classmethod
     def for_levels(cls, names, **kw) -> "CoopTimings":
         tm = cls(**kw)
-        for name in names:
-            tm.levels[name] = {"level_s": 0.0, "rejections": 0}
+        tm.add_levels(names)
         return tm
 
+    def add_levels(self, names) -> None:
+        for name in names:
+            self.levels[name] = {"level_s": 0.0, "rejections": 0}
+
+    def level(self, name: str) -> dict:
+        """The level's sub-dict (created empty on first use)."""
+        return self.levels.setdefault(name, {"level_s": 0.0, "rejections": 0})
+
     def add_level_time(self, name: str, seconds: float) -> None:
-        self.levels.setdefault(name, {"level_s": 0.0, "rejections": 0})
-        self.levels[name]["level_s"] += seconds
+        self.level(name)["level_s"] += seconds
 
     def add_rejections(self, name: str, count: int) -> None:
-        self.levels.setdefault(name, {"level_s": 0.0, "rejections": 0})
-        self.levels[name]["rejections"] += int(count)
+        self.level(name)["rejections"] += int(count)
 
     # -- mapping back-compat --------------------------------------------------
     _FIELDS = (

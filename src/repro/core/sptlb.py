@@ -23,7 +23,6 @@ per-phase timings.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Literal, Optional
 
 import jax.numpy as jnp
@@ -39,6 +38,7 @@ from repro.core.problem import Problem, bucket_size, pad_problem
 from repro.core.solver_local import LocalSearchConfig, SolveResult, solve_local
 from repro.core.solver_optimal import OptimalSearchConfig, solve_optimal
 from repro.core.telemetry import ClusterState
+from repro.spans import span
 
 Engine = Literal["local", "optimal", "greedy-cpu", "greedy-mem", "greedy-task"]
 
@@ -212,25 +212,25 @@ class Sptlb:
             # shard co-location) fire inside ``cooperate`` via cfg.plan.
             solve_cluster = dataclasses.replace(
                 base_cluster, problem=plan.apply(base_cluster.problem))
-        t0 = time.perf_counter()
-        greedy_timings = None
-        if engine.startswith("greedy-"):
-            # The baseline greedy scheduler is hierarchy-unaware by design —
-            # but the movement budget binds every engine, so its mapping is
-            # priced and trimmed too (no level re-vet: greedy never had the
-            # stack's packing contract).
-            res = solve_fn(solve_cluster.problem)
-            greedy_timings = {}
-            res = enforce_cost_budget(base_cluster, res,
-                                      np.asarray(base_cluster.problem.assignment0),
-                                      cfg.move_cost, cfg.cost_budget, (),
-                                      greedy_timings)
-            coop = None
-        else:
-            coop = cooperate(solve_cluster, solve_fn, config=cfg,
-                             hierarchy=hierarchy)
-            res = coop.result
-        t_solve = time.perf_counter()
+        balance_timings: dict = {}
+        with span("controller.solve", into=balance_timings, key="solve_s"):
+            greedy_timings = None
+            if engine.startswith("greedy-"):
+                # The baseline greedy scheduler is hierarchy-unaware by design —
+                # but the movement budget binds every engine, so its mapping is
+                # priced and trimmed too (no level re-vet: greedy never had the
+                # stack's packing contract).
+                res = solve_fn(solve_cluster.problem)
+                greedy_timings = {}
+                res = enforce_cost_budget(base_cluster, res,
+                                          np.asarray(base_cluster.problem.assignment0),
+                                          cfg.move_cost, cfg.cost_budget, (),
+                                          greedy_timings)
+                coop = None
+            else:
+                coop = cooperate(solve_cluster, solve_fn, config=cfg,
+                                 hierarchy=hierarchy)
+                res = coop.result
 
         # Decision evaluation is against the *served* problem (real collected
         # demand, scaled by any actuated shed caps) — a plan only steers the
@@ -262,19 +262,17 @@ class Sptlb:
                 "churn_cost": shed.churn_cost,
                 "overload_frac": shed.overload_frac,
             }
-        decision = BalanceDecision(
-            assignment=res.assignment,
-            projected=metrics.projected_metrics(problem, res.assignment),
-            violations=constraints.validate(problem, res.assignment),
-            difference_to_balance=metrics.difference_to_balance(problem, res.assignment),
-            network_p99_ms=metrics.network_p99_ms(self.cluster, res.assignment),
-            solve=res,
-            cooperation=coop,
-            movement_cost=movement,
-            budget_trimmed=trimmed,
-        )
-        res.extra["balance_timings"] = {
-            "solve_s": t_solve - t0,
-            "evaluate_s": time.perf_counter() - t_solve,
-        }
+        with span("controller.evaluate", into=balance_timings, key="evaluate_s"):
+            decision = BalanceDecision(
+                assignment=res.assignment,
+                projected=metrics.projected_metrics(problem, res.assignment),
+                violations=constraints.validate(problem, res.assignment),
+                difference_to_balance=metrics.difference_to_balance(problem, res.assignment),
+                network_p99_ms=metrics.network_p99_ms(self.cluster, res.assignment),
+                solve=res,
+                cooperation=coop,
+                movement_cost=movement,
+                budget_trimmed=trimmed,
+            )
+        res.extra["balance_timings"] = balance_timings
         return decision

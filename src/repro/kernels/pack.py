@@ -41,12 +41,13 @@ per-level ``counters()`` hook).
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.spans import span
 
 _TRACE_COUNTS = {"pack_ffd": 0, "pack_ffd_tiers": 0}
 
@@ -72,12 +73,11 @@ class DispatchStats:
     retraces: int = 0
 
     def run(self, fn, *args, **kw) -> np.ndarray:
-        t = time.perf_counter()
-        before = pack_trace_count()
-        out = np.asarray(fn(*args, **kw))      # asarray syncs the device
-        self.retraces += pack_trace_count() - before
-        self.dispatches += 1
-        self.seconds += time.perf_counter() - t
+        with span("bus.pack", into=self, key="seconds"):
+            before = pack_trace_count()
+            out = np.asarray(fn(*args, **kw))      # asarray syncs the device
+            self.retraces += pack_trace_count() - before
+            self.dispatches += 1
         return out
 
 

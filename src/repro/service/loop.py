@@ -29,7 +29,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
-import time
 from typing import Optional
 
 import numpy as np
@@ -38,6 +37,7 @@ from repro.core.controller import BalanceController, TickInput, TickResult
 from repro.service import events as E
 from repro.service.drift import DELTA, FULL, NOOP, DriftConfig, DriftDetector
 from repro.service.shadow import DIRTY_REL, FleetShadow
+from repro.spans import count, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,7 +102,10 @@ class ServiceLoop:
         self.submitted = 0
         self.applied_events = 0
         self._pending_membership = False
-        self.steps: list[ServiceStepResult] = []
+        # Steps taken (the default clock of ``step``); no step result is
+        # kept, so an applied decision lives only as long as its caller
+        # holds it.
+        self.step_count = 0
         self.counts = {NOOP: 0, DELTA: 0, FULL: 0}       # drift decisions
         self.executed = {DELTA: 0, FULL: 0}              # solver actually ran
         self.latency = {NOOP: [], DELTA: [], FULL: []}
@@ -157,26 +160,42 @@ class ServiceLoop:
     # -- one service tick -----------------------------------------------------
     def step(self, now: Optional[int] = None) -> ServiceStepResult:
         """Drain the queue, decide noop/delta/full, run what was decided."""
-        t0 = time.perf_counter()
-        now = len(self.steps) if now is None else int(now)
-        drained = self._drain(now)
+        now = self.step_count if now is None else int(now)
+        clock = {"latency_s": 0.0}
+        with span("service.step", into=clock, key="latency_s",
+                  step=self.step_count, queued=len(self._queue)):
+            out = self._step(now)
+        out.latency_s = clock["latency_s"]
+        self._wall_s += out.latency_s
+        self.counts[out.action] += 1
+        self.latency[out.action].append(out.latency_s)
+        self.step_count += 1
+        return out
+
+    def _step(self, now: int) -> ServiceStepResult:
+        with span("service.drain"):
+            drained = self._drain(now)
 
         ctl = self.controller
-        outlook_active = False
-        if ctl.planner is not None:
-            outlook = ctl.planner.outlook(now, self.shadow.view(now))
-            outlook_active = bool(outlook.active)
-        dirty = self._dirty_shards()
-        decision = self.drift.decide(
-            loads=self.shadow.tier_loads(), now=now,
-            capacity_dirty=self.shadow.capacity_dirty,
-            outlook_active=outlook_active,
-            stranded=self.shadow.stranded(),
-            dirty_shards=dirty,
-            pending_membership=self._pending_membership,
-            d2b=self.shadow.d2b(),
-            over_ideal=self.shadow.over_ideal(),
-            latency_breach=self.shadow.latency_breach)
+        with span("service.scope"):
+            dirty = self._dirty_shards()
+        with span("service.drift"):
+            outlook_active = False
+            if ctl.planner is not None:
+                outlook = ctl.planner.outlook(now, self.shadow.view(now))
+                outlook_active = bool(outlook.active)
+            decision = self.drift.decide(
+                loads=self.shadow.tier_loads(), now=now,
+                capacity_dirty=self.shadow.capacity_dirty,
+                outlook_active=outlook_active,
+                stranded=self.shadow.stranded(),
+                dirty_shards=dirty,
+                pending_membership=self._pending_membership,
+                d2b=self.shadow.d2b(),
+                over_ideal=self.shadow.over_ideal(),
+                latency_breach=self.shadow.latency_breach)
+        count("service.decision", action=decision.action,
+              dirty=len(decision.dirty_shards))
 
         res: Optional[TickResult] = None
         if decision.action is not NOOP:
@@ -197,17 +216,18 @@ class ServiceLoop:
             concluded = res.applied or (
                 not res.triggered and res.reason.startswith("balanced"))
             if concluded:
-                self.shadow.adopt_assignment(
-                    np.asarray(ctl.cluster.problem.assignment0))
-                if decision.action == DELTA:
-                    self.shadow.clean(self._shard_apps(scoped))
-                else:
-                    self.shadow.clean()
-                self._pending_membership = False
-                self.drift.note_solve(self.shadow.tier_loads(),
-                                      full=decision.action == FULL,
-                                      d2b=self.shadow.d2b(),
-                                      over_ideal=self.shadow.over_ideal())
+                with span("service.commit"):
+                    self.shadow.adopt_assignment(
+                        np.asarray(ctl.cluster.problem.assignment0))
+                    if decision.action == DELTA:
+                        self.shadow.clean(self._shard_apps(scoped))
+                    else:
+                        self.shadow.clean()
+                    self._pending_membership = False
+                    self.drift.note_solve(self.shadow.tier_loads(),
+                                          full=decision.action == FULL,
+                                          d2b=self.shadow.d2b(),
+                                          over_ideal=self.shadow.over_ideal())
             if res.triggered:
                 self.executed[decision.action] += 1
             if res.applied:
@@ -216,17 +236,11 @@ class ServiceLoop:
                     .get("sharded", {}).get("delta_reverted")):
                 self.delta_reverts += 1
 
-        latency = time.perf_counter() - t0
-        self._wall_s += latency
-        self.counts[decision.action] += 1
-        self.latency[decision.action].append(latency)
-        out = ServiceStepResult(
+        return ServiceStepResult(
             now=now, action=decision.action, reason=decision.reason,
             divergence=decision.divergence,
             dirty_shards=decision.dirty_shards, result=res,
-            events_drained=drained, latency_s=latency)
-        self.steps.append(out)
-        return out
+            events_drained=drained)
 
     # -- async frontend -------------------------------------------------------
     async def serve(self, queue, *, batch_ticks: bool = True) -> int:
@@ -266,10 +280,10 @@ class ServiceLoop:
         def pct(xs, q):
             return float(np.percentile(xs, q)) if xs else 0.0
 
-        total = max(1, len(self.steps))
+        total = max(1, self.step_count)
         solved = self.executed[DELTA] + self.executed[FULL]
         return {
-            "steps": len(self.steps),
+            "steps": self.step_count,
             "events_submitted": self.submitted,
             "events_applied": self.applied_events,
             "dropped_events": self.dropped_events,

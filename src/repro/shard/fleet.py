@@ -15,7 +15,6 @@ against the real collected problem exactly like ``Sptlb.balance``.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import jax.numpy as jnp
@@ -37,6 +36,7 @@ from repro.shard.partition import (
     stranded_apps,
 )
 from repro.shard.solve import ShardSolveConfig, ShardSolveResult, solve_shards
+from repro.spans import count, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -117,69 +117,72 @@ def solve_fleet(
     """
     cfg = config if config is not None else FleetConfig()
     problem = cluster.problem
-    t0 = time.perf_counter()
-    plan = plan_shards(cluster, cfg.num_shards)
-    sharded = partition_problem(problem, plan)
-    t_partition = time.perf_counter()
+    timings = dict.fromkeys(
+        ("partition_s", "solve_s", "merge_s", "coordinator_s", "total_s"), 0.0)
+    with span("shard.pass", into=timings, key="total_s"):
+        with span("shard.partition", into=timings, key="partition_s"):
+            plan = plan_shards(cluster, cfg.num_shards)
+            sharded = partition_problem(problem, plan)
 
-    dirty = None
-    if dirty_shards is not None:
-        mask = np.zeros(plan.num_shards, bool)
-        arr = np.asarray(dirty_shards)
-        if arr.dtype == bool:
-            mask[: arr.size] = arr[: plan.num_shards]
-        else:
-            ids = arr.astype(np.int64)
-            mask[ids[(ids >= 0) & (ids < plan.num_shards)]] = True
-        dirty = mask
+        dirty = None
+        if dirty_shards is not None:
+            mask = np.zeros(plan.num_shards, bool)
+            arr = np.asarray(dirty_shards)
+            if arr.dtype == bool:
+                mask[: arr.size] = arr[: plan.num_shards]
+            else:
+                ids = arr.astype(np.int64)
+                mask[ids[(ids >= 0) & (ids < plan.num_shards)]] = True
+            dirty = mask
 
-    res = solve_shards(
-        sharded,
-        ShardSolveConfig(
-            max_iters=cfg.max_iters,
-            tol=cfg.tol,
-            batch_moves=cfg.batch_moves,
-            batch_quality=cfg.batch_quality,
-            seed=cfg.seed,
-        ),
-        dirty=dirty,
-    )
-    t_solve = time.perf_counter()
+        with span("shard.solve", into=timings, key="solve_s"):
+            res = solve_shards(
+                sharded,
+                ShardSolveConfig(
+                    max_iters=cfg.max_iters,
+                    tol=cfg.tol,
+                    batch_moves=cfg.batch_moves,
+                    batch_quality=cfg.batch_quality,
+                    seed=cfg.seed,
+                ),
+                dirty=dirty,
+            )
+        # Every lane of the batched solve iterates until the slowest solved
+        # shard converges: the spread of the solved shards' iteration
+        # counts is the lanes' idle share.
+        lanes = res.iterations[res.solved]
+        count("shard.lanes", lanes=int(lanes.size),
+              iters_max=int(lanes.max(initial=0)), iters_sum=int(lanes.sum()))
 
-    merged, reverted = never_worse(
-        problem, merge_assignment(problem, sharded, res.x)
-    )
-    delta_reverted = reverted and dirty is not None and not dirty.all()
-    t_merge = time.perf_counter()
+        with span("shard.merge", into=timings, key="merge_s"):
+            merged, reverted = never_worse(
+                problem, merge_assignment(problem, sharded, res.x)
+            )
+            delta_reverted = reverted and dirty is not None and not dirty.all()
 
-    coordinator = FleetCoordinator(
-        cluster,
-        num_shards=plan.num_shards,
-        saturation=cfg.saturation,
-        migration_frac=cfg.migration_frac,
-        plan=plan,
-    )
-    moves: list = []
-    if cfg.rebalance:
-        moves = coordinator.plan_migrations(
-            problem, merged, move_cost=move_cost, cost_budget=migration_budget
-        )
-        for a, t in moves:
-            merged[a] = t
-    t_coord = time.perf_counter()
+        with span("shard.coordinator", into=timings, key="coordinator_s"):
+            coordinator = FleetCoordinator(
+                cluster,
+                num_shards=plan.num_shards,
+                saturation=cfg.saturation,
+                migration_frac=cfg.migration_frac,
+                plan=plan,
+            )
+            moves: list = []
+            if cfg.rebalance:
+                moves = coordinator.plan_migrations(
+                    problem, merged, move_cost=move_cost, cost_budget=migration_budget
+                )
+                for a, t in moves:
+                    merged[a] = t
 
-    total_s = max(t_coord - t0, 1e-9)
+    total_s = timings["total_s"] = max(timings["total_s"], 1e-9)
     counters = coordinator.counters()
-    timings = {
-        "partition_s": t_partition - t0,
-        "solve_s": t_solve - t_partition,
-        "merge_s": t_merge - t_solve,
-        "coordinator_s": t_coord - t_merge,
-        "total_s": total_s,
-        "solved_shards": int(res.solved.sum()) if res.solved.size else plan.num_shards,
-        "reverted": reverted,
-        "delta_reverted": delta_reverted,
-    }
+    timings.update(
+        solved_shards=int(res.solved.sum()) if res.solved.size else plan.num_shards,
+        reverted=reverted,
+        delta_reverted=delta_reverted,
+    )
     return FleetDecision(
         assignment=merged,
         objective=float(global_objective(problem, jnp.asarray(merged))),
@@ -188,7 +191,7 @@ def solve_fleet(
         migrations=len(moves),
         saturated=int(counters["saturated_shards"]),
         apps_per_s=float(int(np.asarray(problem.valid).sum()) / total_s),
-        coordinator_overhead_frac=(t_coord - t_merge) / total_s,
+        coordinator_overhead_frac=timings["coordinator_s"] / total_s,
         timings=timings,
         sharded=sharded,
         solve=res,
@@ -227,67 +230,65 @@ def balance_fleet(
             base_cluster, problem=plan.apply(base_cluster.problem)
         )
 
-    t0 = time.perf_counter()
     budget = knobs.cost_budget if knobs.cost_budget is not None else float("inf")
-    fd = solve_fleet(
-        solve_cluster,
-        cfg,
-        move_cost=knobs.move_cost,
-        migration_budget=budget,
-        dirty_shards=dirty_shards,
-    )
-    problem = base_cluster.problem
-    res = SolveResult(
-        assignment=jnp.asarray(fd.assignment),
-        iterations=int(max(int(fd.solve.iterations.max()), 1)),
-        converged=bool(fd.solve.converged.all()),
-        objective=float(global_objective(problem, jnp.asarray(fd.assignment))),
-        num_moved=int(
-            np.sum(fd.assignment != np.asarray(problem.assignment0))
-        ),
-        solve_time_s=fd.timings["total_s"],
-        extra={
-            "sharded": {
-                "num_shards": fd.sharded.num_shards,
-                "app_bucket": fd.sharded.app_bucket,
-                "tier_bucket": fd.sharded.tier_bucket,
-                "stranded": fd.stranded,
-                "migrations": fd.migrations,
-                "saturated": fd.saturated,
-                "apps_per_s": fd.apps_per_s,
-                "coordinator_overhead_frac": fd.coordinator_overhead_frac,
-                **fd.timings,
-            }
-        },
-    )
-    timings: dict = {}
-    res = enforce_cost_budget(
-        base_cluster,
-        res,
-        np.asarray(base_cluster.problem.assignment0),
-        knobs.move_cost,
-        budget,
-        (),
-        timings,
-    )
-    t_solve = time.perf_counter()
-    movement = timings.get(
-        "movement_cost",
-        movement_cost_of(res.assignment, problem.assignment0, knobs.move_cost),
-    )
-    decision = BalanceDecision(
-        assignment=res.assignment,
-        projected=metrics.projected_metrics(problem, res.assignment),
-        violations=constraints.validate(problem, res.assignment),
-        difference_to_balance=metrics.difference_to_balance(problem, res.assignment),
-        network_p99_ms=metrics.network_p99_ms(cluster, res.assignment),
-        solve=res,
-        cooperation=None,
-        movement_cost=movement,
-        budget_trimmed=int(timings.get("budget_trimmed", 0)),
-    )
-    res.extra["balance_timings"] = {
-        "solve_s": t_solve - t0,
-        "evaluate_s": time.perf_counter() - t_solve,
-    }
+    balance_timings: dict = {}
+    with span("controller.solve", into=balance_timings, key="solve_s"):
+        fd = solve_fleet(
+            solve_cluster,
+            cfg,
+            move_cost=knobs.move_cost,
+            migration_budget=budget,
+            dirty_shards=dirty_shards,
+        )
+        problem = base_cluster.problem
+        res = SolveResult(
+            assignment=jnp.asarray(fd.assignment),
+            iterations=int(max(int(fd.solve.iterations.max()), 1)),
+            converged=bool(fd.solve.converged.all()),
+            objective=float(global_objective(problem, jnp.asarray(fd.assignment))),
+            num_moved=int(
+                np.sum(fd.assignment != np.asarray(problem.assignment0))
+            ),
+            solve_time_s=fd.timings["total_s"],
+            extra={
+                "sharded": {
+                    "num_shards": fd.sharded.num_shards,
+                    "app_bucket": fd.sharded.app_bucket,
+                    "tier_bucket": fd.sharded.tier_bucket,
+                    "stranded": fd.stranded,
+                    "migrations": fd.migrations,
+                    "saturated": fd.saturated,
+                    "apps_per_s": fd.apps_per_s,
+                    "coordinator_overhead_frac": fd.coordinator_overhead_frac,
+                    **fd.timings,
+                }
+            },
+        )
+        timings: dict = {}
+        res = enforce_cost_budget(
+            base_cluster,
+            res,
+            np.asarray(base_cluster.problem.assignment0),
+            knobs.move_cost,
+            budget,
+            (),
+            timings,
+        )
+    with span("controller.evaluate", into=balance_timings, key="evaluate_s"):
+        movement = timings.get(
+            "movement_cost",
+            movement_cost_of(res.assignment, problem.assignment0, knobs.move_cost),
+        )
+        decision = BalanceDecision(
+            assignment=res.assignment,
+            projected=metrics.projected_metrics(problem, res.assignment),
+            violations=constraints.validate(problem, res.assignment),
+            difference_to_balance=metrics.difference_to_balance(problem, res.assignment),
+            network_p99_ms=metrics.network_p99_ms(cluster, res.assignment),
+            solve=res,
+            cooperation=None,
+            movement_cost=movement,
+            budget_trimmed=int(timings.get("budget_trimmed", 0)),
+        )
+    res.extra["balance_timings"] = balance_timings
     return decision
