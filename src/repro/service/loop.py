@@ -184,15 +184,15 @@ class ServiceLoop:
             if ctl.planner is not None:
                 outlook = ctl.planner.outlook(now, self.shadow.view(now))
                 outlook_active = bool(outlook.active)
+            loads, d2b, over_ideal = self.shadow.drift_inputs()
             decision = self.drift.decide(
-                loads=self.shadow.tier_loads(), now=now,
+                loads=loads, now=now,
                 capacity_dirty=self.shadow.capacity_dirty,
                 outlook_active=outlook_active,
                 stranded=self.shadow.stranded(),
                 dirty_shards=dirty,
                 pending_membership=self._pending_membership,
-                d2b=self.shadow.d2b(),
-                over_ideal=self.shadow.over_ideal(),
+                d2b=d2b, over_ideal=over_ideal,
                 latency_breach=self.shadow.latency_breach)
         count("service.decision", action=decision.action,
               dirty=len(decision.dirty_shards))
@@ -224,10 +224,9 @@ class ServiceLoop:
                     else:
                         self.shadow.clean()
                     self._pending_membership = False
-                    self.drift.note_solve(self.shadow.tier_loads(),
-                                          full=decision.action == FULL,
-                                          d2b=self.shadow.d2b(),
-                                          over_ideal=self.shadow.over_ideal())
+                    loads, d2b, over_ideal = self.shadow.drift_inputs()
+                    self.drift.note_solve(loads, full=decision.action == FULL,
+                                          d2b=d2b, over_ideal=over_ideal)
             if res.triggered:
                 self.executed[decision.action] += 1
             if res.applied:

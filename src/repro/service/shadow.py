@@ -171,10 +171,7 @@ class FleetShadow:
         T = self._capacity.shape[0]
         live = self._valid.copy()
         live[n] = False
-        util = np.zeros_like(self._capacity, np.float64)
-        tsk = np.zeros(T, np.float64)
-        np.add.at(util, self._x0[live], self._demand[live])
-        np.add.at(tsk, self._x0[live], self._tasks[live])
+        util, tsk, _, _ = self._tier_sums(live)
         ok = self._slo_allowed[:, self._slo[n]]
         region_ok = RegionScheduler(self.view()).feasibility_matrix()[n]
         if (ok & region_ok).any():
@@ -215,44 +212,53 @@ class FleetShadow:
         ok = self._slo_allowed[self._x0, self._slo]
         return int(np.sum(~ok & self._valid))
 
+    def _tier_sums(self, live=None):
+        """One pass over the live apps (``valid`` unless ``live`` is given):
+        f64 per-tier sums of demand ``[T, R]`` and tasks ``[T]``, and the
+        f32 fleet totals of both."""
+        idx = np.flatnonzero(self._valid if live is None else live)
+        T, R = self._capacity.shape
+        x = self._x0.take(idx)
+        dem = self._demand.take(idx, axis=0)
+        tsk = self._tasks.take(idx)
+        # bincount adds in f64 in input order, so the sums equal an ordered
+        # f64 scatter-add bit for bit.
+        util = np.stack([np.bincount(x, weights=dem[:, r], minlength=T)
+                         for r in range(R)], axis=1)
+        tier_tasks = np.bincount(x, weights=tsk, minlength=T)
+        return util, tier_tasks, dem.sum(axis=0), tsk.sum()
+
+    def drift_inputs(self) -> tuple[np.ndarray, float, float]:
+        """``(tier_loads, d2b, over_ideal)`` from one pass over the fleet:
+        the drift detector's per-step inputs."""
+        util, tsk, total, total_tasks = self._tier_sums()
+        cap = np.maximum(self._capacity, 1e-9)
+        lim = np.maximum(self._task_limit, 1e-9)
+        util_frac = util / cap
+        task_frac = tsk / lim
+        over = max(float((util_frac - self._ideal).max()),
+                   float((task_frac - self._ideal_t).max()))
+        total_frac = total / cap.sum(axis=0)
+        total_task = total_tasks / lim.sum()
+        d2b = max(float(np.abs(util_frac - total_frac[None, :]).max()),
+                  float(np.abs(task_frac - total_task).max()))
+        return util_frac.max(axis=1), d2b, over
+
     def tier_loads(self) -> np.ndarray:
-        """f32[T] worst-resource load fraction per tier (drift input)."""
-        util = np.zeros_like(self._capacity, np.float64)
-        live = self._valid
-        np.add.at(util, self._x0[live], self._demand[live])
-        return (util / np.maximum(self._capacity, 1e-9)).max(axis=1)
+        """f64[T] worst-resource load fraction per tier (drift input)."""
+        return self.drift_inputs()[0]
 
     def over_ideal(self) -> float:
         """Worst excess over the ideal utilization line — the quantity the
         lockstep ``trigger_over_ideal`` polices and the SLO accountant
         integrates as over-ideal tier-ticks."""
-        live = self._valid
-        cap = np.maximum(self._capacity, 1e-9)
-        lim = np.maximum(self._task_limit, 1e-9)
-        util = np.zeros_like(self._capacity, np.float64)
-        tsk = np.zeros(cap.shape[0], np.float64)
-        np.add.at(util, self._x0[live], self._demand[live])
-        np.add.at(tsk, self._x0[live], self._tasks[live])
-        over = float((util / cap - self._ideal).max())
-        return max(over, float((tsk / lim - self._ideal_t).max()))
+        return self.drift_inputs()[2]
 
     def d2b(self) -> float:
         """Difference-to-balance of the shadow incumbent — the same Fig. 5
         metric the lockstep trigger polices (``core.metrics``), in plain
         numpy so quiescent ticks stay cheap."""
-        live = self._valid
-        cap = np.maximum(self._capacity, 1e-9)
-        lim = np.maximum(self._task_limit, 1e-9)
-        util = np.zeros_like(self._capacity, np.float64)
-        tsk = np.zeros(cap.shape[0], np.float64)
-        np.add.at(util, self._x0[live], self._demand[live])
-        np.add.at(tsk, self._x0[live], self._tasks[live])
-        util_frac = util / cap
-        task_frac = tsk / lim
-        total_frac = self._demand[live].sum(axis=0) / cap.sum(axis=0)
-        total_task = self._tasks[live].sum() / lim.sum()
-        worst = float(np.abs(util_frac - total_frac[None, :]).max())
-        return max(worst, float(np.abs(task_frac - total_task).max()))
+        return self.drift_inputs()[1]
 
     def view(self, now: int | None = None) -> ClusterState:
         """The shadow as a ``ClusterState`` the controller can plan on."""
