@@ -1,0 +1,9 @@
+"""Mean ``service.scope`` span (ms) per quiet step (drift decision noop):
+the shadow's view and the dirty shards it scopes.
+
+Read from the traced run's program spans (``span_reduce.LAYER_NUMBERS``);
+None without a trace or without its span or counter."""
+
+
+def read(run):
+    return None if run.spans is None else run.spans["layers"]["frontend.scope_ms"]

@@ -2,14 +2,19 @@
 """Several runs of one cell in one process: rate sweeps and limit readings.
 
     python3 bench/probe.py --workload <cell> --seeds 11,12,13 --seconds 40 \
-        [--rates 1,2,4] [--traffic <file.json>] [--control] [--out <file.jsonl>]
+        [--rates 1,2,4] [--traffic <file.json>] [--control] [--trace 0|1] \
+        [--chips 4] [--controller-args '{"shards": 16}'] [--out <file.jsonl>]
 
 Each (rate, seed) pair is one ``bench/run.py`` run, in this process, so the
 chip is reached and the programs are compiled once.  ``--rates`` scales the
 traffic file's reading rate (every process's ``report_period_s`` divided by
 the factor); arcs and surges keep their periods; ``--traffic`` tries
-another traffic file in the cell's place.
-``--control`` also reads the check's control (the reference's
+another traffic file in the cell's place.  ``--chips`` and
+``--controller-args`` run the cell on that many chips (under a mesh) with
+that controller (the configuration's ``controller_args``; where they name
+``shards``, without the configuration's region latency budget, which a
+sharded full pass does not keep): a trial of a cell that ``BENCHMARK.json``
+does not hold, metrics chosen as for the cell named.  ``--control`` also reads the check's control (the reference's
 objective in bfloat16 in the device's place) on the same applied passes.
 Each run prints one JSON line with the seed, the rate, the result and the
 numbers compared.  This is a measuring tool: no benchmark run calls it, and
@@ -46,19 +51,29 @@ def main(argv=None) -> int:
     ap.add_argument("--rates", default="1")
     ap.add_argument("--traffic", help="a traffic file to use in place of the cell's")
     ap.add_argument("--control", action="store_true")
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--chips", type=int, help="run the cell on this many chips")
+    ap.add_argument("--controller-args", help="JSON object: the configuration's controller_args")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
-    _, _, _, traffic = bench_run.load_cell(args.workload)
+    _, cell, config, traffic = bench_run.load_cell(args.workload)
     if args.traffic:
         traffic = json.loads(pathlib.Path(args.traffic).read_text())
+    if args.chips:
+        cell = dict(cell, chips=args.chips)
+    if args.controller_args:
+        config = dict(config, controller_args=json.loads(args.controller_args))
+        if config["controller_args"].get("shards"):
+            # a sharded full pass keeps no region budget (run.sharded_full_passes)
+            config.pop("region_latency_budget_ms", None)
     out = open(args.out, "a") if args.out else None
     try:
         for rate in [float(r) for r in args.rates.split(",")]:
             for seed in [int(s) for s in args.seeds.split(",")]:
                 t0 = time.time()
-                result = bench_run.run(args.workload, seed, args.seconds, False,
+                result = bench_run.run(args.workload, seed, args.seconds, bool(args.trace),
                                        control=args.control, traffic=scaled(traffic, rate),
-                                       t_process=t0)
+                                       cell=cell, config=config, t_process=t0)
                 line = json.dumps({"workload": args.workload, "seed": seed, "rate": rate,
                                    "wall_s": time.time() - t0, **result})
                 print(line, flush=True)

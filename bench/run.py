@@ -10,17 +10,21 @@ configuration (``bench/configs/<config>.json``) and a traffic mix
 
 1. refuses a machine whose JAX finds no TPU, or fewer chips than the cell
    asks for (exit 2, no result);
-2. turns on JAX's persistent compilation cache (``repro.compile_cache``);
-3. builds the fleet from the seed (``bench/fleets.py``);
-4. builds every event due in the window, from the seed alone
+2. for a cell of several chips, makes a mesh over them (one axis,
+   ``data``, over which the batched shard solve spreads its shards), in
+   scope from the warm-up to the window's close; a one-chip cell runs with
+   no mesh;
+3. turns on JAX's persistent compilation cache (``repro.compile_cache``);
+4. builds the fleet from the seed (``bench/fleets.py``);
+5. builds every event due in the window, from the seed alone
    (``bench/schedule.py``);
-5. warms every shape the window uses, as the traffic file's ``warm_up``
+6. warms every shape the window uses, as the traffic file's ``warm_up``
    says: a full cooperation pass that balances the fleet (the window starts
    from its placement), one more on the demand the schedule reports by each
    instant under ``full_at_s``, and, where the traffic makes delta passes
    (``delta``), one over each count of dirty shards the batched shard solve
    can see;
-6. measures for ``--seconds``: when an event falls due the harness submits
+7. measures for ``--seconds``: when an event falls due the harness submits
    every event that is due and calls ``ServiceLoop.step()``, as the loop's
    own ``serve(batch_ticks=True)`` does; when none is due it sleeps until
    the next one is.  An event's decision latency runs from its due time to
@@ -28,18 +32,26 @@ configuration (``bench/configs/<config>.json``) and a traffic mix
    queued behind it (open loop).  Events due in the window whose step ends
    after it closes are still timed.
 
-After the window the f64 reference (``bench/reference.py``) replays the
-event log against every applied placement; that decides ``correct``.  The
+The controller is ``ControllerConfig()`` unless the configuration file
+carries ``controller_args`` (scalar fields of ``ControllerConfig``, such as
+``{"shards": 32}`` for sharded full passes, which run no region or host
+level, so such a configuration states no region latency budget).  After the
+window the f64 reference (``bench/reference.py``) replays the event log
+against every applied placement, and each full pass is judged by the
+engine the configuration names; that decides ``correct``.  The
 last line of standard output is one JSON object: ``correct``, ``attempted``
 (events due in the window), ``failed`` (events dropped or whose step
 raised), ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1``
-its per-layer metrics, each read by ``bench/metrics/<name>.py``), ``device``
-and, last, ``checks``: each number compared beside its limit.  The same
-numbers end standard error.
+its per-layer metrics, each read by ``bench/metrics/<name>.py`` from the
+run's records, its device trace or the program's spans), ``device`` and,
+last, ``checks``: each number compared beside its limit.  The same numbers
+end standard error.
 
 ``--rehearse-on-cpu`` (with ``--apps``/``--tiers``) is for the tests under
 ``bench/tests`` only: it accepts the CPU backend at a small size and prints
-no device metric.  ``run(..., control=True)`` (``bench/probe.py --control``)
+no device metric; ``run(..., cell=..., config=...)`` takes a cell and a
+configuration that ``BENCHMARK.json`` does not hold (rehearsals and
+``bench/probe.py`` only).  ``run(..., control=True)`` (``bench/probe.py --control``)
 also reads the check's control: the reference's objective computed in
 bfloat16 in the device's place; no benchmark run asks for it.
 """
@@ -52,12 +64,14 @@ T_PROCESS = time.time()
 
 import argparse  # noqa: E402
 import collections  # noqa: E402
+import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import json  # noqa: E402
 import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import typing  # noqa: E402
 
 import numpy as np  # noqa: E402
 
@@ -69,6 +83,7 @@ import fleets  # noqa: E402
 import plugins  # noqa: E402
 import reference  # noqa: E402
 import schedule as schedule_mod  # noqa: E402
+import span_reduce  # noqa: E402
 import trace_reduce  # noqa: E402
 
 # Limits of the numbers compared (see PERF.md for the readings behind each).
@@ -80,12 +95,15 @@ class NoChip(RuntimeError):
     pass
 
 
-def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
+def load_cell(name: str, cell: dict | None = None) -> tuple[dict, dict, dict, dict]:
+    """The benchmark's spec, the cell (``cell`` where given, else the entry
+    ``name`` of ``BENCHMARK.json``), its configuration and its traffic."""
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    cells = {c["name"]: c for c in spec["workloads"]}
-    if name not in cells:
-        raise SystemExit(f"unknown workload {name!r}; have {sorted(cells)}")
-    cell = cells[name]
+    if cell is None:
+        cells = {c["name"]: c for c in spec["workloads"]}
+        if name not in cells:
+            raise SystemExit(f"unknown workload {name!r}; have {sorted(cells)}")
+        cell = cells[name]
     config = json.loads((BENCH / "configs" / f"{cell['config']}.json").read_text())
     traffic = json.loads((BENCH / "traffic" / f"{cell['traffic']}.json").read_text())
     return spec, cell, config, traffic
@@ -100,6 +118,38 @@ def _say(*parts) -> None:
     print(*parts, file=sys.stderr, flush=True)
 
 
+def controller_config(args: dict):
+    """``ControllerConfig(**args)``, where ``args`` (a configuration's
+    ``controller_args``) may name only scalar fields of ``ControllerConfig``,
+    each with a value of its type; anything else raises."""
+    from repro.core.controller import ControllerConfig
+
+    hints = typing.get_type_hints(ControllerConfig)
+    for key, value in args.items():
+        kinds = set(typing.get_args(hints.get(key)) or (hints.get(key),)) - {type(None)}
+        if not kinds or not kinds <= {bool, int, float, str}:
+            raise ValueError(f"controller_args: {key!r} is not a scalar field of ControllerConfig")
+        ok = value is None and type(None) in typing.get_args(hints[key])
+        ok = ok or type(value) in kinds or (type(value) is int and float in kinds)
+        if not ok:
+            raise ValueError(f"controller_args: {key}={value!r} is not of type {hints[key]}")
+    return ControllerConfig(**args)
+
+
+def sharded_full_passes(config: dict) -> bool:
+    """Whether the configuration's full passes are the sharded pass
+    (``controller_args`` names ``shards``) rather than the cooperation bus.
+
+    A sharded full pass runs neither the region nor the host level, so a
+    configuration that names ``shards`` and states a region latency budget
+    claims a guarantee its engine does not give: it raises."""
+    sharded = bool((config.get("controller_args") or {}).get("shards"))
+    if sharded and config.get("region_latency_budget_ms") is not None:
+        raise ValueError("a configuration whose controller_args name shards runs no region or "
+                         "host level in its full passes: it may not state region_latency_budget_ms")
+    return sharded
+
+
 @dataclasses.dataclass
 class RunData:
     """What one run recorded; the metric readers take their numbers from it."""
@@ -111,7 +161,8 @@ class RunData:
     steps: list = dataclasses.field(default_factory=list)
     d2b_mean: float | None = None
     compiles_in_window: int = 0
-    trace: dict | None = None
+    trace: dict | None = None           # trace_reduce's device numbers
+    spans: dict | None = None           # span_reduce's reduction of the program's spans
 
 
 # ---------------------------------------------------------------------------
@@ -141,42 +192,58 @@ def _reported_by(fleet, sched, start, upto_s: float):
         tasks=jnp.asarray(ref.tasks, jnp.float32)))
 
 
-def warm_up(fleet, sched, num_shards: int, spec: dict):
+def warm_up(fleet, sched, spec: dict, cfg, service):
     """Compile what the window runs, on a controller of its own that is told
-    to solve every time, and return the fleet the window starts from.
+    to solve every time, and return the window's ``ServiceLoop`` over the
+    placement it starts from, with what was warmed.
 
-    A full cooperation pass balances the fleet as built: the window starts
-    from that placement, the steady state of a fleet this controller runs.
-    More full passes, from that placement, on the demand the schedule's
-    events report by each of ``spec["full_at_s"]``'s instants, compile the
-    feedback buckets that traffic asks for.  Where the traffic makes delta
-    passes (``spec["delta"]``), a delta pass over 1..S dirty shards (the
-    batched solver compiles once per count) for each shard shape those
-    placements give."""
-    from repro.core.controller import BalanceController, ControllerConfig, TickInput
+    A full pass balances the fleet as built: the window starts from that
+    placement, the steady state of a fleet this controller runs.  More full
+    passes, from that placement, on the demand the schedule's events report
+    by each of ``spec["full_at_s"]``'s instants, compile the feedback
+    buckets that traffic asks for.  Where the traffic makes delta passes
+    (``spec["delta"]``), a delta pass over 1..S dirty shards (the batched
+    solver compiles once per count), S the loop's own shard count, for each
+    shard shape those placements give.  The warm-up's controllers are the
+    window's (``cfg``, so a configuration that names ``shards`` warms
+    sharded full passes), told to solve every time: full passes with
+    ``trigger_d2b=-1``, both with no cooldown.  Every pass after the first
+    is handed the fleet as the window hands it over, a ``FleetShadow``'s
+    view, so that under a mesh its arrays are laid out as the window's
+    are."""
+    from repro.core.controller import BalanceController, TickInput
+    from repro.service import ServiceLoop
+    from repro.service.shadow import FleetShadow
 
     cluster = fleets.to_cluster(fleet)
-    ctl = BalanceController(cluster, ControllerConfig(trigger_d2b=-1.0, cooldown_rounds=0))
+    ctl = BalanceController(cluster, dataclasses.replace(cfg, trigger_d2b=-1.0,
+                                                         cooldown_rounds=0))
     ctl.step(TickInput(cluster=cluster, now=0))
     start = ctl.cluster
     solved = [start]
     for now, t in enumerate(spec.get("full_at_s", ()), start=1):
-        state = _reported_by(fleet, sched, start, t)
+        state = FleetShadow(_reported_by(fleet, sched, start, t)).view(now)
         res = ctl.step(TickInput(cluster=state, now=now))
         solved.append(ctl.cluster if res.applied else state)
+    loop = ServiceLoop(controller=BalanceController(start, cfg), config=service)
     if not spec.get("delta", False):
-        return start, {"full_passes": len(solved)}
+        return loop, {"full_passes": len(solved)}
+    # A delta request is solved once the cooldown has passed, whatever the
+    # triggers say, so these passes keep the window's triggers and run its
+    # whole decision.
+    ctl = BalanceController(start, dataclasses.replace(cfg, cooldown_rounds=0))
     shapes, now = set(), len(solved)
     for c in solved:
-        shape = _shard_shape(c, num_shards)
+        shape = _shard_shape(c, loop.num_shards)
         if shape in shapes:
             continue
         shapes.add(shape)
+        view = FleetShadow(c).view(now)
         for k in range(1, shape[0] + 1):
-            ctl.step(TickInput(cluster=c, now=now, dirty_shards=tuple(range(k)),
-                               num_shards=num_shards))
+            ctl.step(TickInput(cluster=view, now=now, dirty_shards=tuple(range(k)),
+                               num_shards=loop.num_shards))
             now += 1
-    return start, {"full_passes": len(solved), "shard_shapes": sorted(shapes)}
+    return loop, {"full_passes": len(solved), "shard_shapes": sorted(shapes)}
 
 
 # ---------------------------------------------------------------------------
@@ -276,10 +343,16 @@ def measure(loop, sched, seconds: float, events) -> tuple[list, float, int]:
 
 def check(fleet, config, sched, steps, control: bool) -> dict:
     """Replay the events against every applied placement with the f64
-    reference.  Returns the numbers compared."""
+    reference.  Returns the numbers compared.
+
+    A full pass is judged by the engine the configuration names, not by what
+    its record says ran: where ``controller_args`` names ``shards`` it must
+    be a sharded pass that solved every shard, else a pass of the whole
+    cooperation bus, with the region and host levels' guarantees."""
     import jax.numpy as jnp
 
-    ref = reference.Reference(fleet, region_budget_ms=float(config["region_latency_budget_ms"]))
+    sharded_full = sharded_full_passes(config)
+    ref = reference.Reference(fleet, region_budget_ms=config.get("region_latency_budget_ms"))
     applied = 0
     gaps, control_gaps, fails, mismatch = [], [], [], 0
     for rec in steps:
@@ -289,7 +362,8 @@ def check(fleet, config, sched, steps, control: bool) -> dict:
             continue
         applied += 1
         where = f"step at {rec['t0']:.3f} s ({rec['action']})"
-        bus = rec["action"] == "full" and not rec["delta"]
+        full = rec["action"] == "full" and not rec["delta"]
+        bus = full and not sharded_full
         mismatch += int(np.sum(np.any(rec["demand"] != ref.demand.astype(np.float32), axis=1)))
         broken, gap, obj, obj0 = ref.check(rec["x"], rec["x_inc"], rec["reported"], bus=bus)
         gaps.append(gap)
@@ -299,10 +373,18 @@ def check(fleet, config, sched, steps, control: bool) -> dict:
         if obj > obj0 + OBJ_GAP_LIMIT * max(abs(obj0), reference.OBJECTIVE_FLOOR):
             broken.append(f"objective {obj!r} worse than the incumbent's {obj0!r}")
         if bus:
+            if "solved_shards" in rec:
+                broken.append("sharded full pass in a bus configuration")
             if not {"region", "host"} <= set(rec.get("levels", ())):
                 broken.append(f"full pass levels {rec.get('levels')}")
             if rec.get("pack_dispatches", 0) < 1:
                 broken.append("full pass packed no host bins")
+        elif full:
+            if "levels" in rec:
+                broken.append("bus full pass in a sharded configuration")
+            if "solved_shards" not in rec or rec["solved_shards"] != rec["num_shards"]:
+                broken.append(f"full pass solved {rec.get('solved_shards')} of "
+                              f"{rec.get('num_shards')} shards")
         if rec["delta"] and rec.get("solved_shards", 0) < 1:
             broken.append("delta pass solved no shards")
         fails += [f"{where}: {b}" for b in broken]
@@ -321,25 +403,35 @@ def check(fleet, config, sched, steps, control: bool) -> dict:
 
 def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         rehearse: bool = False, control: bool = False, sizes: dict | None = None,
-        traffic: dict | None = None, t_process: float | None = None) -> dict:
-    """One run of a cell; returns the result line as a dict.  ``sizes`` and
-    ``traffic`` override the configuration's sizes and the traffic file
-    (rehearsals and ``bench/probe.py`` only)."""
+        traffic: dict | None = None, cell: dict | None = None, config: dict | None = None,
+        t_process: float | None = None) -> dict:
+    """One run of a cell; returns the result line as a dict.  ``sizes``,
+    ``traffic``, ``cell`` and ``config`` override the configuration's sizes,
+    the traffic file, the cell's entry in ``BENCHMARK.json`` and the
+    configuration file (rehearsals and ``bench/probe.py`` only); the metrics
+    are those ``BENCHMARK.json`` gives ``cell_name``."""
     import jax
 
-    spec, cell, config, cell_traffic = load_cell(cell_name)
+    spec, cell, cell_config, cell_traffic = load_cell(cell_name, cell)
     traffic = cell_traffic if traffic is None else traffic
+    config = cell_config if config is None else config
     t_process = T_PROCESS if t_process is None else t_process
     devices = jax.devices()
     dev = devices[0]
+    chips = int(cell["chips"])
     if dev.platform != "tpu" and not rehearse:
         raise NoChip(f"JAX finds no TPU (platform {dev.platform!r}); this benchmark runs on a TPU")
-    if len(devices) < int(cell["chips"]):
-        raise NoChip(f"the cell asks for {cell['chips']} chips, JAX finds {len(devices)}")
+    if len(devices) < chips:
+        raise NoChip(f"the cell asks for {chips} chips, JAX finds {len(devices)}")
+    used = devices[:chips]
+    # A cell of several chips runs under a mesh over them; one chip, none.
+    mesh = jax.make_mesh((chips,), ("data",), devices=used) if chips > 1 else None
     sys.path.insert(0, str(ROOT / "src"))
     from repro.compile_cache import enable_compile_cache
-    from repro.service import ServiceConfig, ServiceLoop
+    from repro.service import ServiceConfig
 
+    cfg = controller_config(config.get("controller_args") or {})
+    sharded_full_passes(config)     # a configuration that claims what its engine lacks raises
     _say(f"bench: compile cache {enable_compile_cache()}")
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
@@ -351,31 +443,35 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     sched = schedule_mod.build(fleet, traffic, seed, seconds)
     events = [plugins.load("events", kind).program_event(payload, due)
               for kind, payload, due in zip(sched.kinds, sched.payloads, sched.due)]
-    num_shards = int(config["service"]["num_shards"])
-    start, warm = warm_up(fleet, sched, num_shards, traffic["warm_up"])
-    loop = ServiceLoop(start, config=ServiceConfig(num_shards=num_shards))
-    prime = loop.step()     # the drift detector's baseline
-    x_start = np.asarray(loop.controller.cluster.problem.assignment0).copy()
-    _say(f"bench: {cell_name} seed {seed}: {fleet.num_apps} apps x {fleet.num_tiers} tiers, "
-         f"{len(sched)} events due in {seconds} s; warm-up {warm}; "
-         f"priming step {prime.action} (applied {prime.applied})")
+    service = ServiceConfig(num_shards=int(config["service"]["num_shards"]))
+    # The mesh is in scope from the warm-up to the window's close.
+    with jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+        loop, warm = warm_up(fleet, sched, traffic["warm_up"], cfg, service)
+        prime = loop.step()     # the drift detector's baseline
+        x_start = np.asarray(loop.controller.cluster.problem.assignment0).copy()
+        _say(f"bench: {cell_name} seed {seed}: {fleet.num_apps} apps x {fleet.num_tiers} tiers, "
+             f"{len(sched)} events due in {seconds} s; warm-up {warm}; "
+             f"priming step {prime.action} (applied {prime.applied})")
 
-    trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
-    if trace:
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 1
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    setup_s = time.time() - t_process
-    steps, t_start, undrained = measure(loop, sched, seconds, events)
-    t_close = t_start + seconds
-    if trace:
-        t0 = time.perf_counter()
-        jax.profiler.stop_trace()
-        _say(f"bench: trace written in {time.perf_counter() - t0:.1f} s")
+        trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
+        if trace:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 1
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        setup_s = time.time() - t_process
+        steps, t_start, undrained = measure(loop, sched, seconds, events)
+        t_close = t_start + seconds
+        if trace:
+            t0 = time.perf_counter()
+            jax.profiler.stop_trace()
+            _say(f"bench: trace written in {time.perf_counter() - t0:.1f} s")
     dropped = loop.dropped_events
-    memory = (dev.memory_stats() or {}).get("peak_bytes_in_use")
-    del loop, start
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in used]
+    memory = max((p for p in peaks if p is not None), default=None)
+    if len(used) > 1:
+        _say(f"bench: peak bytes in use per device {dict(zip(map(str, used), peaks))}")
+    del loop
 
     data = RunData(cell=cell_name, seconds=seconds, setup_s=setup_s)
     t_end = max(t_close, t_start + (steps[-1]["t1"] if steps else 0.0))
@@ -395,10 +491,16 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         files = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
         if files:
+            from jax.profiler import ProfileData
+
             t0 = time.perf_counter()
-            data.trace = trace_reduce.reduce_file(files[-1], [r["action"] for r in steps])
+            profile = ProfileData.from_file(str(files[-1]))
+            data.trace = trace_reduce.reduce_profile(profile, [r["action"] for r in steps])
+            data.spans = span_reduce.reduce_profile(profile)
+            del profile
             _say(f"bench: trace of {files[-1].stat().st_size} bytes reduced in "
-                 f"{time.perf_counter() - t0:.1f} s")
+                 f"{time.perf_counter() - t0:.1f} s; device planes "
+                 f"{data.trace['num_devices'] if data.trace else 0}")
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     metrics = {}
@@ -419,13 +521,14 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     correct = (failed == 0 and checks["applied_passes"] >= 1 and checks["rule_breaks"] == 0
                and checks["demand_mismatch"] == 0 and checks["obj_gap"] <= OBJ_GAP_LIMIT)
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": len(devices),
-              "memory_peak_bytes": memory}
+              "chips": len(used), "memory_peak_bytes": memory}
     result = {"correct": bool(correct), "attempted": len(sched), "failed": failed,
               "metrics": metrics, "device": device}
     if trace and data.trace is not None:
         device.update(busy_s=data.trace["busy_s"], window_s=data.trace["window_s"])
         result["breakdown"] = {"device_ops": data.trace["device_ops"],
-                               "idle_gaps": data.trace["idle_gaps"]}
+                               "idle_gaps": data.trace["idle_gaps"],
+                               "idle_by_span": data.spans["idle_by_span"][:10]}
     result["checks"] = {k: {"value": v, "limit": lim} for k, (v, lim) in compared.items()}
     if control:
         result["checks"]["obj_gap_program"] = {"value": checks["obj_gap_program"],

@@ -8,10 +8,16 @@ Reads the trace with ``jax.profiler.ProfileData`` and nothing else:
   the intervals of its ``XLA Ops`` events inside the window, averaged over
   the devices; the idle share is 1 - busy / window;
 * device time per jitted module: the durations of the ``XLA Modules``
-  events, keyed by module name with its ``(<id>)`` suffix dropped;
-* the top device ops by time, and the longest stretches of device idle
-  time, each cut at the edges of the harness spans (``bench.*``) on the host
-  and named after the span the host was in.
+  events, keyed by module name with its ``(<id>)`` suffix dropped.  Every
+  chip runs a module's executions in the same order, so the k-th event of
+  a module on each plane is one execution; an execution takes as long as
+  its slowest chip, which is what a pass over a mesh waits for, and the
+  module's time is the sum of those (``modules_n`` counts the events on
+  every plane).  On one device this is the plain sum;
+* the top device ops by time on the chip that spent most on each, and the
+  longest stretches of device idle time, each cut at the edges of the
+  harness spans (``bench.*``) on the host and named after the span the
+  host was in.
 
 A trace without a device plane (a run on the CPU) yields no device numbers.
 """
@@ -108,25 +114,37 @@ def reduce_profile(profile, step_labels=(), top: int = 10) -> dict | None:
     lo, hi = windows[0]
     window_ns = hi - lo
     busy_ns, gaps = [], []
-    op_ns = collections.Counter()
-    mod_ns, mod_n = collections.Counter(), collections.Counter()
+    op_ns = collections.Counter()           # op -> most time on one device
+    mod_ns = collections.defaultdict(list)  # module -> slowest device per execution
+    mod_n = collections.Counter()
     for plane in devices:
         ops = _line(plane, "XLA Ops")
         events = list(ops.events) if ops is not None else []
         spans = _clip([(e.start_ns, e.end_ns) for e in events], lo, hi)
         merged = _union(spans)
         busy_ns.append(sum(e - s for s, e in merged))
+        ops_here = collections.Counter()
         for e in events:
             if lo <= e.start_ns < hi:
-                op_ns[op_name(e.name)] += e.duration_ns
+                ops_here[op_name(e.name)] += e.duration_ns
+        for name, ns in ops_here.items():
+            op_ns[name] = max(op_ns[name], ns)
         edges = [lo] + [v for se in merged for v in se] + [hi]
         gaps += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
                  if edges[i + 1] > edges[i]]
         mods = _line(plane, "XLA Modules")
-        for e in (mods.events if mods is not None else ()):
+        runs = collections.defaultdict(list)
+        for e in sorted(mods.events if mods is not None else (), key=lambda e: e.start_ns):
             if lo <= e.start_ns < hi:
-                mod_ns[module_name(e.name)] += e.duration_ns
-                mod_n[module_name(e.name)] += 1
+                runs[module_name(e.name)].append(e.duration_ns)
+        for name, durations in runs.items():
+            mod_n[name] += len(durations)
+            slowest = mod_ns[name]
+            for k, ns in enumerate(durations):
+                if k < len(slowest):
+                    slowest[k] = max(slowest[k], ns)
+                else:
+                    slowest.append(ns)
     inner = sorted((s, e, n) for n, s, e in host_spans if n != WINDOW_SPAN)
     steps = iter(step_labels)
     inner = [(s, e, f"{n}.{next(steps, 'unlabelled')}" if n == STEP_SPAN else n)
@@ -137,7 +155,7 @@ def reduce_profile(profile, step_labels=(), top: int = 10) -> dict | None:
         "window_s": window_ns / 1e9,
         "busy_s": busy / 1e9,
         "idle_frac": 1.0 - busy / window_ns if window_ns > 0 else None,
-        "modules_s": {k: v / 1e9 for k, v in mod_ns.items()},
+        "modules_s": {k: sum(v) / 1e9 for k, v in mod_ns.items()},
         "modules_n": dict(mod_n),
         "device_ops": [[n, v / 1e9] for n, v in op_ns.most_common(top)],
         "idle_gaps": [[n, d / 1e9] for n, d in segments],
