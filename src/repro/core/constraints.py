@@ -93,7 +93,8 @@ def validate(problem: Problem, assignment: jax.Array,
 
 def move_mask(problem: Problem, assignment: jax.Array,
               util: jax.Array, tasks: jax.Array,
-              moves_left: jax.Array) -> jax.Array:
+              moves_left: jax.Array,
+              feasible: jax.Array | None = None) -> jax.Array:
     """bool[N, T]: is moving app n to tier t feasible *right now*?
 
     Encodes constraints 1-4 incrementally:
@@ -102,9 +103,12 @@ def move_mask(problem: Problem, assignment: jax.Array,
            (moving an already-moved app again, or back home, is budget-neutral
            or budget-freeing)
       4:   SLO table + avoid matrix membership.
+
+    ``feasible`` is ``problem.feasible_mask()`` computed once by a caller
+    that builds masks in a loop; it does not depend on the assignment.
     """
-    N, T = problem.num_apps, problem.num_tiers
-    feas = problem.feasible_mask()                              # SLO + avoid
+    T = problem.num_tiers
+    feas = problem.feasible_mask() if feasible is None else feasible  # SLO + avoid
 
     # Capacity feasibility at destination: util[t] + d[n] <= C[t] (both resources).
     fits = destination_fits(problem.demand, problem.tasks, problem.capacity,

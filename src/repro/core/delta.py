@@ -37,6 +37,19 @@ import jax
 import jax.numpy as jnp
 
 
+def at_tier(table: jax.Array, src: jax.Array) -> jax.Array:
+    """``table[src]`` for every app, as a select over the tier axis.
+
+    Exactly one term of each sum is non-zero, so this is bit-identical to
+    the gather.  Inside the sweep loop a gather with an app-sized result is
+    not fused; this select-and-reduce is, with apps on the lanes.
+    """
+    T = table.shape[0]
+    onehot = src[:, None] == jnp.arange(T)[None, :]              # [N, T]
+    onehot = onehot.reshape(onehot.shape + (1,) * (table.ndim - 1))
+    return jnp.sum(jnp.where(onehot, table[None], 0.0), axis=1)
+
+
 def move_delta_cost(
     demand: jax.Array,        # f32[N, R]
     tasks: jax.Array,         # f32[N]
@@ -63,11 +76,11 @@ def move_delta_cost(
     mean_f = jnp.mean(f, axis=0)                 # [R]
     mean_g = jnp.mean(g)
 
-    # Per-app source-tier quantities.
+    # Per-app source-tier quantities (selects, not gathers: see at_tier).
     src = assignment                             # [N]
-    C_src = capacity[src]                        # [N, R]
-    f_src = f[src]                               # [N, R]
-    ideal_src = ideal_frac[src]                  # [N, R]
+    C_src = at_tier(capacity, src)               # [N, R]
+    f_src = at_tier(f, src)                      # [N, R]
+    ideal_src = at_tier(ideal_frac, src)         # [N, R]
     d_over_Csrc = demand / C_src                 # [N, R]
     f_src_new = f_src - d_over_Csrc              # [N, R]
 
@@ -96,9 +109,9 @@ def move_delta_cost(
     d_under_ideal = jnp.sum(d_hinge, axis=-1)                      # [N, T]
 
     # --- task-count analogues (goals 5 + 7) ---
-    K_src = task_limit[src]                                        # [N]
-    g_src = g[src]
-    gideal_src = ideal_task_frac[src]
+    K_src = at_tier(task_limit, src)                               # [N]
+    g_src = at_tier(g, src)
+    gideal_src = at_tier(ideal_task_frac, src)
     k_over_Ksrc = tasks / K_src
     g_src_new = g_src - k_over_Ksrc
 

@@ -122,7 +122,7 @@ def _solve_local_jit(problem: Problem, key: jax.Array, x_init: jax.Array,
         x, util, tasks, it, _, committed, key = state
         moves_left = C.moves_remaining(problem, x)
         delta = eval_fn(*sweep_args(x, util, tasks))
-        mask = C.move_mask(problem, x, util, tasks, moves_left)
+        mask = C.move_mask(problem, x, util, tasks, moves_left, feas)
         scores = jnp.where(mask, delta, jnp.inf)
 
         key, sub = jax.random.split(key)
@@ -160,7 +160,7 @@ def _solve_local_jit(problem: Problem, key: jax.Array, x_init: jax.Array,
                                           feas, moves_left)
         else:
             delta = eval_fn(*sweep_args(x, util, tasks))
-            mask = C.move_mask(problem, x, util, tasks, moves_left)
+            mask = C.move_mask(problem, x, util, tasks, moves_left, feas)
             scores = jnp.where(mask, delta, jnp.inf)
             best_t = jnp.argmin(scores, axis=1).astype(jnp.int32)
             best_s = jnp.min(scores, axis=1)
