@@ -2,20 +2,13 @@
 
 1. collect a 5-tier stream-processing cluster (paper §4 setup),
 2. balance it with SPTLB under manual_cnst hierarchy co-operation,
-3. compare against the greedy baseline (paper Fig. 3),
-4. train a reduced assigned-architecture model on SPTLB-routed streams.
+3. compare against the greedy baseline (paper Fig. 3).
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
-import jax
 import numpy as np
 
 from repro import CoopConfig, Sptlb, generate_cluster, utilization_fraction
-from repro.models import build_model, reduce_for_smoke
-from repro.configs import get_config
-from repro.streams import StreamConfig, TokenStream
-from repro.train.optimizer import AdamWConfig
-from repro.train.train_step import init_train_state, make_train_step
 
 
 def main():
@@ -49,22 +42,6 @@ def main():
           f"accepted: {coop.cooperation.accepted}")
     print(f"worst-case net latency: {coop.network_p99_ms:.0f} ms "
           f"(vs {balanced.network_p99_ms:.0f} ms hierarchy-blind)")
-
-    # --- 4: train a reduced assigned arch on the routed streams ------------
-    print("\n== train smollm-360m (reduced) for 10 steps ==")
-    cfg = reduce_for_smoke(get_config("smollm-360m"))
-    model = build_model(cfg)
-    stream = TokenStream(StreamConfig(vocab_size=cfg.vocab_size, seq_len=64,
-                                      global_batch=8))
-    state = init_train_state(model, jax.random.PRNGKey(0))
-    step = jax.jit(make_train_step(model, AdamWConfig(lr=1e-3)))
-    import jax.numpy as jnp
-    for i in range(10):
-        batch = {k: jnp.asarray(v) for k, v in stream.batch(i).items()}
-        state, metrics = step(state, batch)
-        if i % 3 == 0 or i == 9:
-            print(f"step {i}: loss {float(metrics['loss']):.4f}")
-    print("done.")
 
 
 if __name__ == "__main__":

@@ -4,7 +4,7 @@
   * all three integration variants (no_cnst / w_cnst / manual_cnst),
   * both engines (LocalSearch / OptimalSearch),
   * a failure event mid-scenario -> capacity shrink -> movement-bounded
-    re-balance (the framework's fault-tolerance loop).
+    re-balance (the scheduler's fault-tolerance loop).
 
 Run:  PYTHONPATH=src python examples/rebalance_cluster.py [--apps 600]
 

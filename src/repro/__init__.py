@@ -1,4 +1,4 @@
-"""repro: SPTLB hierarchical multi-objective scheduling + JAX training framework.
+"""repro: SPTLB hierarchical multi-objective scheduling of stream-processing apps.
 
 The curated public surface.  Everything an integrator needs for the three
 supported workflows imports from here:

@@ -1,8 +1,6 @@
 from repro.distributed import sharding
-from repro.distributed.checkpoint import CheckpointManager
-from repro.distributed.compress import GradCompressor
-from repro.distributed.fault import (CapacityEvent, FaultInjector, Recovery,
-                                     degrade, rebalance)
+from repro.distributed.fault import (CapacityEvent, FaultInjector, degrade,
+                                     rebalance)
 
-__all__ = ["sharding", "CheckpointManager", "GradCompressor", "CapacityEvent",
-           "FaultInjector", "Recovery", "degrade", "rebalance"]
+__all__ = ["sharding", "CapacityEvent", "FaultInjector", "degrade",
+           "rebalance"]

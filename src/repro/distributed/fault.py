@@ -1,4 +1,4 @@
-"""Fault tolerance & elasticity — where the framework meets the paper.
+"""Fault tolerance & elasticity: infrastructure events as capacity changes.
 
 The cluster is organized exactly like the paper's tiers: pod slices with
 capacity headroom in three dimensions (compute FLOP/s, HBM bytes, stream-task
@@ -7,8 +7,8 @@ slots).  Failures and stragglers are *capacity events*:
   * host failure      -> the tier's capacity shrinks; jobs whose demand no
                          longer fits must move.  SPTLB re-solves with the
                          movement-minimizing objective (paper goal 8) so only
-                         the displaced work moves (checkpoint/restore cost
-                         is the "downtime" the paper's task-count movement
+                         the displaced work moves (a moved app's restart is
+                         the "downtime" the paper's task-count movement
                          cost models).
   * straggler host    -> detected from step-time telemetry; modeled as a
                          fractional capacity reduction, which biases SPTLB
@@ -21,21 +21,18 @@ Capacity events are **one representation away from the simulator**: every
 ``CapacityEvent`` converts (``to_timed``) into a ``sim.events.CapacityScale``,
 and all cluster rewrites go through the sim's knob/refresh contract
 (``sim.events.FleetState.refresh``) — one code path whether a tier degrades
-inside a fleet trajectory or under the training loop's one-shot recovery.
+inside a fleet trajectory or under a one-shot ``rebalance``.
 Announced events (planned scale-ups, telemetry-detected stragglers) also
 publish ``core.planner.Advisory`` records, so a ``BalanceController`` fed by
 ``FaultInjector.schedule`` anticipates them exactly like declared
 maintenance; hard host failures stay surprises.
 
-``FaultInjector`` drives simulated events for tests/examples; ``Recovery``
-implements the restart path: restore latest checkpoint -> rebuild mesh over
-the surviving devices -> re-route streams via SPTLB.
-
+``FaultInjector`` drives simulated events for tests/examples.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional
+from typing import Optional
 
 import jax.numpy as jnp
 import numpy as np
@@ -165,21 +162,3 @@ def rebalance(cluster: ClusterState, *events,
         jnp.asarray(decision.assignment))
     rebalanced = dataclasses.replace(degraded, problem=new_problem)
     return rebalanced, decision
-
-
-
-@dataclasses.dataclass
-class Recovery:
-    """Checkpoint-restart path used by launch/train.py."""
-
-    ckpt_manager: object                  # distributed.checkpoint.CheckpointManager
-    rebuild_mesh: Callable[[], object]    # () -> Mesh over surviving devices
-    on_rebalance: Optional[Callable] = None
-
-    def recover(self, template_state):
-        """-> (state, step): restore the latest complete checkpoint."""
-        state, step = self.ckpt_manager.restore(template_state)
-        mesh = self.rebuild_mesh()
-        if self.on_rebalance is not None:
-            self.on_rebalance(mesh)
-        return state, step, mesh

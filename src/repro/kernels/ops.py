@@ -1,6 +1,6 @@
 """jit'd dispatch wrappers: one entry point per kernel, impl-selectable.
 
-``impl="xla"``    — pure-jnp path (CPU container, dry-run lowering, oracle)
+``impl="xla"``    — pure-jnp path (the solver's own path, and the oracle)
 ``impl="pallas"`` — Pallas TPU kernel, compiled for the TPU the process
                     runs on.  Off a TPU it raises unless the caller asks for
                     the interpreter explicitly with ``interpret=True`` (the
@@ -12,13 +12,9 @@ not start a JAX backend (and with it claim the chip).
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 
 from repro.kernels import ref as _ref
-from repro.kernels.flash_attention import flash_attention as _flash_pallas
-from repro.kernels.mamba_scan import ssd_chunked_kernel as _mamba_pallas
 from repro.kernels.move_eval import move_eval_best_pallas as _move_best_pallas
 from repro.kernels.move_eval import move_eval_pallas as _move_pallas
 
@@ -52,35 +48,3 @@ def move_eval_best(*args, impl: str = "xla", interpret: bool = False):
     if impl == "xla":
         return _ref.move_eval_best_ref(*args)
     return _move_best_pallas(*args, interpret=_pallas_interpret(interpret))
-
-
-def flash_attention(q, k, v, *, causal: bool = True,
-                    window: Optional[int] = None,
-                    softcap: Optional[float] = None,
-                    scale: Optional[float] = None,
-                    impl: str = "xla", interpret: bool = False):
-    if impl == "xla":
-        return _ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                        softcap=softcap, scale=scale)
-    return _flash_pallas(q, k, v, causal=causal, window=window,
-                         softcap=softcap, scale=scale,
-                         interpret=_pallas_interpret(interpret))
-
-
-def mamba_scan(x, dt, A, Bm, Cm, D, h0=None, *, impl: str = "xla",
-               interpret: bool = False):
-    if impl == "xla":
-        return _ref.mamba_scan_ref(x, dt, A, Bm, Cm, D, h0)
-    return _mamba_pallas(x, dt, A, Bm, Cm, D, h0,
-                         interpret=_pallas_interpret(interpret))
-
-
-def flash_decode(q, k, v, kv_len, *, scale=None, softcap=None,
-                 impl: str = "xla", interpret: bool = False):
-    """Single-token decode attention over an append-only KV cache."""
-    if impl == "xla":
-        return _ref.flash_decode_ref(q, k, v, kv_len, scale=scale,
-                                     softcap=softcap)
-    from repro.kernels.flash_decode import flash_decode as _fd
-    return _fd(q, k, v, kv_len, scale=scale, softcap=softcap,
-               interpret=_pallas_interpret(interpret))

@@ -1,62 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel (the ``ref.py`` contract).
 
-Each oracle shares its math with the production XLA path so kernel tests
-pin the Pallas implementations to the exact semantics the framework uses.
+Each oracle is the solver's own XLA path, so the kernel tests pin the
+Pallas implementations to the exact semantics the solver uses.
 """
-from __future__ import annotations
+from repro.core.delta import move_best_per_app as move_eval_best_ref
+from repro.core.delta import move_delta_cost as move_eval_ref
 
-from typing import Optional
-
-import jax
-import jax.numpy as jnp
-
-# move_eval oracles == the solver's XLA path (single source of truth).
-from repro.core.delta import move_best_per_app as move_eval_best_ref  # noqa: F401
-from repro.core.delta import move_delta_cost as move_eval_ref  # noqa: F401
-
-# mamba chunked-scan oracle == the model's XLA path.
-from repro.models.mamba2 import ssd_chunked as mamba_scan_ref  # noqa: F401
-
-
-def flash_attention_ref(
-    q, k, v, *,
-    causal: bool = True,
-    window: Optional[int] = None,
-    softcap: Optional[float] = None,
-    scale: Optional[float] = None,
-):
-    """Dense GQA attention oracle.  q: [B,Sq,H,D]; k/v: [B,Skv,KV,D]."""
-    B, Sq, H, D = q.shape
-    KV = k.shape[2]
-    G = H // KV
-    scale = scale if scale is not None else D ** -0.5
-
-    qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, KV, G, D)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", qf, k.astype(jnp.float32))
-    if softcap is not None:
-        logits = softcap * jnp.tanh(logits / softcap)
-
-    q_pos = jnp.arange(Sq)[:, None]
-    k_pos = jnp.arange(k.shape[1])[None, :]
-    mask = jnp.ones((Sq, k.shape[1]), bool)
-    if causal:
-        mask &= k_pos <= q_pos
-    if window is not None:
-        mask &= k_pos > q_pos - window
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32))
-    return out.reshape(B, Sq, H, D).astype(q.dtype)
-
-
-def flash_decode_ref(q, k, v, kv_len, *, scale=None, softcap=None):
-    """Decode-attention oracle: q [B,1,H,D] over cache positions < kv_len."""
-    B, _, H, D = q.shape
-    Smax = k.shape[1]
-    kv_pos = jnp.broadcast_to(jnp.arange(Smax, dtype=jnp.int32), (B, Smax))
-    kv_valid = kv_pos < kv_len
-    q_positions = jnp.full((B, 1), Smax, jnp.int32)   # all cache is past
-    from repro.models.layers import attention
-    return attention(q, k, v, causal=False, q_positions=q_positions,
-                     kv_positions=kv_pos, kv_valid=kv_valid,
-                     softcap=softcap, scale=scale)
+__all__ = ["move_eval_ref", "move_eval_best_ref"]

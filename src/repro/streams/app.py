@@ -1,6 +1,6 @@
 """Stream-application model: the unit SPTLB schedules.
 
-A ``StreamApp`` is a training/serving job fed by a partitioned token stream.
+A ``StreamApp`` is a streaming job that consumes a partitioned input stream.
 Its scheduler-visible footprint is exactly the paper's app record:
 p99 compute/memory demand, task count (= stream partitions), SLO class,
 criticality, and a data-source region.
@@ -21,7 +21,6 @@ class StreamApp:
     slo: int                       # latency class
     criticality: float             # [0, 1]
     data_region: int
-    arch: str = "smollm-360m"      # model served/trained by this job
 
 
 def demo_apps(num: int = 32, *, num_regions: int = 6, seed: int = 0

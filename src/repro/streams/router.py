@@ -1,8 +1,8 @@
 """SPTLB-driven routing of stream apps onto pod slices.
 
-Bridges the paper's scheduler to the training runtime: StreamApps become the
-solver's entities, pod slices become tiers, and the resulting app->tier
-mapping tells each slice which stream partitions to consume.
+Bridges the paper's scheduler to a stream-processing runtime: StreamApps
+become the solver's entities, pod slices become tiers, and the resulting
+app->tier mapping tells each slice which stream partitions to consume.
 """
 from __future__ import annotations
 
@@ -29,6 +29,22 @@ class PodSlice:
     hbm_capacity: float            # GB
     task_slots: int
     regions: tuple[int, ...]
+
+
+def default_slices() -> list[PodSlice]:
+    """A 5-tier cluster matching the paper's experiment setup."""
+    return [
+        PodSlice("tier_1", pod=0, num_hosts=64, flops_capacity=900.0,
+                 hbm_capacity=2048.0, task_slots=1500, regions=(0, 1)),
+        PodSlice("tier_2", pod=0, num_hosts=48, flops_capacity=700.0,
+                 hbm_capacity=1536.0, task_slots=1200, regions=(1, 2)),
+        PodSlice("tier_3", pod=0, num_hosts=32, flops_capacity=400.0,
+                 hbm_capacity=1024.0, task_slots=800, regions=(2, 3)),
+        PodSlice("tier_4", pod=1, num_hosts=48, flops_capacity=700.0,
+                 hbm_capacity=1536.0, task_slots=1200, regions=(3, 4)),
+        PodSlice("tier_5", pod=1, num_hosts=64, flops_capacity=900.0,
+                 hbm_capacity=2048.0, task_slots=1500, regions=(4, 5)),
+    ]
 
 
 def build_cluster(apps: list[StreamApp], slices: list[PodSlice],

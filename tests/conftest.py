@@ -1,5 +1,5 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests must see 1 CPU device
-(the 512-device override belongs to launch/dryrun.py only)."""
+(tests that need several virtual devices set them in a subprocess)."""
 import jax
 import numpy as np
 import pytest

@@ -5,14 +5,14 @@
 * a Pallas kernel never drops into interpret mode on its own;
 * the compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else to
   ``<checkout>/.jax_cache``;
-* ``place_shard_batch`` spreads a shard batch over a ``jax.set_mesh`` mesh
-  of four (virtual) devices, and the sharded solve gives the same answer as
-  off-mesh;
+* ``place_shard_batch`` leaves a shard batch alone off-mesh and on one
+  device, spreads it over a ``jax.set_mesh`` mesh of four (virtual)
+  devices, and the sharded solve gives the same answer as off-mesh;
 * ``chip_smoke.py`` refuses a machine without a TPU, fails without the
   package beside it, and passes its CPU rehearsal at a small size.
 
 The multi-device and fresh-interpreter checks run in child processes with
-``JAX_PLATFORMS=cpu``, like tests/test_extensions.py.
+``JAX_PLATFORMS=cpu``.
 """
 
 import json
@@ -23,9 +23,11 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.distributed.sharding import place_shard_batch
 from repro.kernels import ops
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
@@ -87,6 +89,24 @@ def test_compile_cache_directory(tmp_path, env_dir):
         assert used == configured == str(ROOT / ".jax_cache")
     else:
         assert used == configured == str(tmp_path / env_dir)
+
+
+@pytest.mark.parametrize("case", ["off_mesh", "one_device_mesh", "no_batch_leaf"])
+def test_place_shard_batch_on_one_device(case):
+    """In-process, on the one CPU device: off-mesh and under a one-device
+    ``data`` mesh the tree and ``S`` pass through untouched; a tree with no
+    ``[S, ...]`` leaf is refused."""
+    tree = {"x": jnp.arange(12.0).reshape(3, 4), "k": jnp.arange(3)}
+    if case == "no_batch_leaf":
+        with pytest.raises(ValueError, match=r"\[S, \.\.\.\] leaf"):
+            place_shard_batch({"scalar": jnp.float32(1.0), "meta": None})
+        return
+    if case == "off_mesh":
+        placed, S = place_shard_batch(tree)
+    else:
+        with jax.set_mesh(jax.make_mesh((1,), ("data",))):
+            placed, S = place_shard_batch(tree)
+    assert placed is tree and S == 3
 
 
 def test_shard_batch_spans_a_four_device_mesh():

@@ -1,114 +1,11 @@
-"""Distribution substrate: sharding specs, checkpoint roundtrip, fault events,
-optimizer behaviour."""
-import dataclasses
-
-import jax
-import jax.numpy as jnp
+"""Fault events: capacity degradation, movement-bounded rebalance, and the
+injector's schedule in the simulator's event representation."""
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
 
-from repro.configs import get_config
-from repro.distributed import sharding as SH
-from repro.distributed.checkpoint import CheckpointManager
+from repro.core import generate_cluster
 from repro.distributed.fault import (CapacityEvent, FaultInjector,
                                      degrade, rebalance)
-from repro.core import generate_cluster
-from repro.launch.mesh import make_host_mesh
-from repro.models import build_model, reduce_for_smoke
-from repro.train.optimizer import (AdamWConfig, adamw_init, adamw_update,
-                                   lr_schedule)
-from repro.train.train_step import init_train_state, make_train_step
-
-
-# ---------------------------------------------------------------------------
-# sharding rules
-# ---------------------------------------------------------------------------
-
-def test_param_specs_column_row():
-    spec = SH.param_spec(("layers", 0, "attn", "wq"),
-                         jax.ShapeDtypeStruct((42, 1024, 2048), jnp.float32))
-    assert spec == P(None, None, "model")
-    spec = SH.param_spec(("layers", 0, "attn", "wo"),
-                         jax.ShapeDtypeStruct((42, 2048, 1024), jnp.float32))
-    assert spec == P(None, "model", None)
-    spec = SH.param_spec(("embed",),
-                         jax.ShapeDtypeStruct((50304, 1024), jnp.float32))
-    assert spec == P("model", None)
-
-
-def test_moe_experts_are_expert_parallel():
-    spec = SH.param_spec(("layers", 0, "moe", "w_gate"),
-                         jax.ShapeDtypeStruct((24, 32, 1024, 512), jnp.float32))
-    # stacked [L, E, d, f] -> expert axis sharded
-    assert spec == P(None, "model", None, None)
-
-
-def test_sanitize_drops_indivisible():
-    mesh = make_host_mesh(data=1, model=1)
-    spec = SH.sanitize(P(None, "model"), (10, 7), mesh)   # 7 % 1 == 0 -> kept
-    assert spec == P(None, "model")
-
-
-def test_full_tree_shardings_build():
-    """Sharding specs build for every arch's full-size param tree."""
-    mesh = make_host_mesh(data=1, model=1)
-    for arch in ("gemma2-9b", "deepseek-v2-lite-16b", "zamba2-2.7b",
-                 "xlstm-125m", "hubert-xlarge"):
-        cfg = get_config(arch)
-        model = build_model(cfg)
-        abs_params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        sh = SH.params_shardings(mesh, abs_params)
-        assert len(jax.tree.leaves(sh)) == len(jax.tree.leaves(abs_params))
-
-
-# ---------------------------------------------------------------------------
-# checkpointing
-# ---------------------------------------------------------------------------
-
-def test_checkpoint_roundtrip(tmp_path):
-    cfg = reduce_for_smoke(get_config("smollm-360m"))
-    model = build_model(cfg)
-    state = init_train_state(model, jax.random.PRNGKey(0))
-    mgr = CheckpointManager(tmp_path, keep=2)
-    mgr.save(5, state)
-    restored, step = mgr.restore(state)
-    assert step == 5
-    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(restored)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_checkpoint_retention_and_latest(tmp_path):
-    mgr = CheckpointManager(tmp_path, keep=2)
-    tree = {"w": jnp.arange(8.0)}
-    for s in (1, 2, 3, 4):
-        mgr.save(s, tree)
-    assert mgr.all_steps() == [3, 4]
-    assert mgr.latest_step() == 4
-
-
-def test_checkpoint_async(tmp_path):
-    mgr = CheckpointManager(tmp_path, keep=3)
-    tree = {"w": jnp.arange(128.0)}
-    mgr.save(7, tree, blocking=False)
-    mgr.wait()
-    restored, step = mgr.restore(tree)
-    assert step == 7
-    np.testing.assert_array_equal(np.asarray(restored["w"]),
-                                  np.asarray(tree["w"]))
-
-
-def test_checkpoint_rejects_shape_mismatch(tmp_path):
-    mgr = CheckpointManager(tmp_path)
-    mgr.save(1, {"w": jnp.zeros(4)})
-    with pytest.raises(ValueError):
-        mgr.restore({"w": jnp.zeros(8)})
-
-
-def test_checkpoint_atomic_no_tmp_left(tmp_path):
-    mgr = CheckpointManager(tmp_path)
-    mgr.save(3, {"w": jnp.zeros(4)})
-    assert not list(tmp_path.glob("*.tmp"))
 
 
 # ---------------------------------------------------------------------------
@@ -165,58 +62,3 @@ def test_injector_schedule_unifies_with_sim_events():
     for adv, t in zip(advisories, announced):
         assert (adv.at, adv.tier) == (t.at, t.tier)
         assert adv.scale == pytest.approx(t.scale)
-
-
-
-# ---------------------------------------------------------------------------
-# optimizer
-# ---------------------------------------------------------------------------
-
-def test_adamw_reduces_quadratic():
-    cfg = AdamWConfig(lr=0.1, warmup_steps=0, total_steps=100,
-                      weight_decay=0.0, grad_clip=100.0)
-    params = {"w": jnp.asarray([3.0, -2.0])}
-    state = adamw_init(params)
-    for _ in range(50):
-        grads = {"w": 2 * params["w"]}
-        params, state, m = adamw_update(cfg, grads, state, params)
-    assert float(jnp.abs(params["w"]).max()) < 1.0
-
-
-def test_lr_schedule_warmup_and_decay():
-    cfg = AdamWConfig(lr=1e-3, warmup_steps=10, total_steps=100)
-    assert float(lr_schedule(cfg, jnp.asarray(0))) == 0.0
-    assert abs(float(lr_schedule(cfg, jnp.asarray(10))) - 1e-3) < 1e-9
-    assert float(lr_schedule(cfg, jnp.asarray(100))) < 2e-4
-
-
-def test_grad_clip_limits_update():
-    cfg = AdamWConfig(lr=1.0, warmup_steps=0, grad_clip=1e-3,
-                      weight_decay=0.0)
-    params = {"w": jnp.zeros(4)}
-    state = adamw_init(params)
-    _, _, metrics = adamw_update(cfg, {"w": jnp.full(4, 1e6)}, state, params)
-    assert float(metrics["grad_norm"]) > 1e5   # raw norm reported
-
-
-def test_microbatched_step_matches_full():
-    cfg = reduce_for_smoke(get_config("smollm-360m"))
-    cfg = dataclasses.replace(cfg, remat=False)
-    model = build_model(cfg)
-    batch = {
-        "tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
-                                     cfg.vocab_size),
-        "targets": jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0,
-                                      cfg.vocab_size),
-    }
-    s0 = init_train_state(model, jax.random.PRNGKey(0))
-    full = make_train_step(model)(s0, batch)
-    s0b = init_train_state(model, jax.random.PRNGKey(0))
-    micro = make_train_step(model, microbatches=2)(s0b, batch)
-    np.testing.assert_allclose(float(full[1]["loss"]),
-                               float(micro[1]["loss"]), rtol=1e-4)
-    for a, b in zip(jax.tree.leaves(full[0].params),
-                    jax.tree.leaves(micro[0].params)):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   atol=1e-5, rtol=1e-4)

@@ -1,86 +1,13 @@
-"""Streaming substrate: determinism, seekability, routing, backpressure."""
-import time
+"""Stream frontend: build_cluster's field mapping, routing onto pod slices,
+and re-routing after a capacity event."""
+import dataclasses
 
 import numpy as np
 import pytest
 
-from repro.streams import (BackpressureError, Prefetcher, StreamConfig,
-                           StreamRouter, TokenStream, build_cluster,
+from repro.distributed.fault import CapacityEvent, rebalance
+from repro.streams import (StreamRouter, build_cluster, default_slices,
                            demo_apps)
-from repro.launch.train import default_slices
-
-
-def test_stream_deterministic_and_seekable():
-    cfg = StreamConfig(vocab_size=512, seq_len=32, global_batch=8)
-    a = TokenStream(cfg)
-    b = TokenStream(cfg)
-    for step in (0, 5, 1000):
-        np.testing.assert_array_equal(a.batch(step)["tokens"],
-                                      b.batch(step)["tokens"])
-    # different steps differ
-    assert not np.array_equal(a.batch(0)["tokens"], a.batch(1)["tokens"])
-
-
-def test_stream_batch_shape_any_partition_count():
-    for gb, parts in ((8, 16), (16, 5), (32, 32)):
-        cfg = StreamConfig(vocab_size=128, seq_len=16, global_batch=gb,
-                           num_partitions=parts)
-        batch = TokenStream(cfg).batch(0)
-        assert batch["tokens"].shape == (gb, 16)
-        assert batch["targets"].shape == (gb, 16)
-
-
-def test_targets_are_shifted_tokens():
-    cfg = StreamConfig(vocab_size=128, seq_len=16, global_batch=4)
-    s = TokenStream(cfg)
-    raw = s.sample(0, 0)
-    batch = s.batch(0)
-    np.testing.assert_array_equal(batch["tokens"][0], raw[0, :-1])
-    np.testing.assert_array_equal(batch["targets"][0], raw[0, 1:])
-
-
-def test_prefetcher_produces_sequential_steps():
-    cfg = StreamConfig(vocab_size=128, seq_len=8, global_batch=4, prefetch=2)
-    pf = Prefetcher(TokenStream(cfg), start_step=0)
-    steps = [next(pf)["_step"] for _ in range(4)]
-    pf.close()
-    assert steps == [0, 1, 2, 3]
-    assert pf.stats.consumed == 4
-    assert pf.stats.produced >= 4
-
-
-def test_prefetcher_counts_stalls_and_keeps_pending_batch():
-    # Tiny queue, fast stall clock, generous max_stalls: the worker must
-    # stall (consumer drains nothing for a while), keep the pending batch,
-    # and deliver every step exactly once when draining resumes.
-    cfg = StreamConfig(vocab_size=64, seq_len=4, global_batch=2, prefetch=1,
-                       stall_timeout_s=0.02, max_stalls=10_000)
-    pf = Prefetcher(TokenStream(cfg), start_step=0)
-    deadline = time.monotonic() + 5.0
-    while pf.stats.stalls < 3 and time.monotonic() < deadline:
-        time.sleep(0.01)
-    assert pf.stats.stalls >= 3
-    steps = [next(pf)["_step"] for _ in range(5)]
-    pf.close()
-    assert steps == [0, 1, 2, 3, 4]          # no step skipped or repeated
-    assert pf.stats.max_stall_run >= 3
-    assert pf.stats.dropped == pf.stats.produced - pf.stats.consumed
-
-
-def test_prefetcher_raises_on_wedged_consumer():
-    cfg = StreamConfig(vocab_size=64, seq_len=4, global_batch=2, prefetch=1,
-                       stall_timeout_s=0.01, max_stalls=3)
-    pf = Prefetcher(TokenStream(cfg), start_step=0)
-    try:
-        # Never consume: the worker trips max_stalls and parks the error.
-        deadline = time.monotonic() + 5.0
-        while pf._error is None and time.monotonic() < deadline:
-            time.sleep(0.01)
-        with pytest.raises(BackpressureError):
-            next(pf)
-        assert pf.stats.max_stall_run >= 3
-    finally:
-        pf.close()
 
 
 def test_router_routes_apps_to_slices():
@@ -93,3 +20,72 @@ def test_router_routes_apps_to_slices():
     total = sum(len(router.partitions_for_tier(t, apps))
                 for t in range(5))
     assert total == len(apps)
+
+
+@pytest.mark.parametrize("kind,fraction", [
+    ("host_failure", 0.2), ("straggler", 0.1), ("scale_up", 0.25)])
+def test_router_rides_a_capacity_event(kind, fraction):
+    """Route, then re-route after a capacity event on the routed cluster:
+    the result is feasible, moves stay within the movement budget, and
+    every app's partitions land on exactly one slice."""
+    apps = demo_apps(48, seed=0)
+    slices = default_slices()
+    router = StreamRouter(build_cluster(apps, slices, seed=0))
+    routed = router.route(engine="local", variant="manual_cnst")
+    assert routed.violations.ok
+    cluster = dataclasses.replace(
+        router.cluster,
+        problem=router.cluster.problem.with_assignment0(routed.assignment))
+
+    event = CapacityEvent(kind, tier=2, fraction=fraction)
+    rebalanced, decision = rebalance(cluster, event)
+    before = np.asarray(cluster.problem.capacity)[2]
+    after = np.asarray(rebalanced.problem.capacity)[2]
+    np.testing.assert_allclose(after, before * event.factor, rtol=1e-6)
+    assert decision.violations.ok
+    assert (decision.projected.num_moved
+            <= int(cluster.problem.move_budget))
+
+    router.cluster = rebalanced
+    router.assignment = np.asarray(decision.assignment)
+    placed = [router.partitions_for_tier(t, apps) for t in range(len(slices))]
+    names = [name for tier in placed for name in tier]
+    assert sorted(names) == sorted(a.name for a in apps)
+    parts = {name: n for tier in placed for name, n in tier.items()}
+    assert parts == {a.name: a.num_partitions for a in apps}
+
+
+# field -> (what carries it, its column in the assembled ClusterState)
+_FIELDS = {
+    "flops_demand": ("app", lambda c: c.problem.demand[:, 0]),
+    "hbm_demand": ("app", lambda c: c.problem.demand[:, 1]),
+    "num_partitions": ("app", lambda c: c.problem.tasks),
+    "slo": ("app", lambda c: c.problem.slo),
+    "criticality": ("app", lambda c: c.problem.criticality),
+    "data_region": ("app", lambda c: c.app_region),
+    "flops_capacity": ("slice", lambda c: c.problem.capacity[:, 0]),
+    "hbm_capacity": ("slice", lambda c: c.problem.capacity[:, 1]),
+    "task_slots": ("slice", lambda c: c.problem.task_limit),
+    "regions": ("slice", lambda c: c.tier_regions),
+}
+
+
+@pytest.mark.parametrize("field", sorted(_FIELDS))
+def test_build_cluster_carries_field(field):
+    """Each StreamApp / PodSlice field lands, row for row, in the
+    ClusterState column the scheduler reads it from."""
+    num_regions = 6
+    apps = demo_apps(24, num_regions=num_regions, seed=3)
+    slices = default_slices()
+    cluster = build_cluster(apps, slices, num_regions=num_regions, seed=3)
+    owner, column = _FIELDS[field]
+    got = np.asarray(column(cluster))
+    if field == "regions":
+        want = np.zeros((len(slices), num_regions), bool)
+        for t, s in enumerate(slices):
+            want[t, list(s.regions)] = True
+    else:
+        rows = apps if owner == "app" else slices
+        want = np.array([getattr(r, field) for r in rows], np.float32)
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(got.astype(want.dtype), want)
